@@ -89,14 +89,14 @@ drill-tp:
 
 # Warm-start resize drill (docs/OPERATIONS.md "Warm starts and the
 # compile cache" — ISSUE 20's done bar): three fresh engine
-# processes sharing one --compile-cache dir — cold populate, then a
+# processes sharing one cold compile-cache dir (handed to them through
+# JAX_COMPILATION_CACHE_DIR, the one resolver) — cold populate, then a
 # requeue/--resume restart and a replay, both of which must load
 # every step executable from the persistent AOT store (2 hits, 0
-# compiled, 0 fallback dispatches), wash the restored state before
-# the first donated dispatch, and land startup at a fraction of the
-# cold compile. Prints cold-vs-warm startup and process-wall JSON
-# lines; paste the summary numbers into docs/OPERATIONS.md when the
-# hardware or jax pin changes.
+# compiled, 0 fallback dispatches) and land startup at a fraction of
+# the cold compile. Prints cold-vs-warm startup and process-wall JSON
+# lines. CPU-hosted: its seconds are not device metrics (the chip's
+# cold/warm start-up is what chip_smoke.py prints).
 drill-warmstart:
 	env JAX_PLATFORMS=cpu $(PY) benchmarks/warmstart.py
 

@@ -5,14 +5,18 @@ attempt with the same compile fingerprint) — the number that decides
 whether an elastic exec-restart lands inside the preemption deadline
 (docs/OPERATIONS.md "Warm starts and the compile cache").
 
-Three fresh engine processes share one ``--compile-cache`` dir:
+Three fresh engine processes share one compile-cache dir — a COLD
+temporary root by design (the drill measures the cold attempt), handed
+to the children the way any deployment places the cache: through
+``JAX_COMPILATION_CACHE_DIR`` in their environment
+(``compilecache.resolve_cache_dir``), not a second code path:
 
 1. ``cold``    — first attempt ever: compiles both step executables,
                  serializes them into the store (0 hits / 2 saved).
 2. ``requeue`` — the requeue/restart path: same fingerprint, fresh
                  process, ``--resume``; must load both executables
-                 (2 hits / 0 compiled) and wash the restored state
-                 before the first dispatch.
+                 (2 hits / 0 compiled) and step the restored
+                 (host-committed) state through them.
 3. ``replay``  — a second warm attempt, confirming the verdict is
                  stable (the store, not an OS page cache accident).
 
@@ -22,8 +26,10 @@ process wall — jax import, mesh init, model build and data pipeline
 included — because the resize deadline is paid in process wall, not
 compile seconds. Prints one JSON line per phase plus a summary line
 with the warm/cold ratios; exits non-zero if the warm attempts fail
-to load from the store. CPU-hosted (8 fake devices) like every other
-drill; on a real pod the same script measures the real thing."""
+to load from the store. CPU-hosted (8 virtual devices, --backend=cpu
+children) like every other drill — its seconds are CPU seconds, never
+a device metric; the chip's cold/warm start-up is what
+``chip_smoke.py`` prints."""
 
 from __future__ import annotations
 
@@ -48,9 +54,9 @@ cfg = Config(arch="resnet18", image_size=16, num_classes=4,
              dataset="synthetic", synthetic_size=128, workers=0,
              bf16=False, log_every=0, seed=0, save_model=True,
              resume=(phase != "cold"),
+             backend="cpu",
              log_dir=os.path.join(root, "tb"),
-             ckpt_dir=os.path.join(root, "ck"),
-             compile_cache=os.path.join(root, "cc"))
+             ckpt_dir=os.path.join(root, "ck"))
 result = run(cfg)
 sys.exit(0 if result["best_epoch"] >= 0 else 1)
 """
@@ -58,7 +64,9 @@ sys.exit(0 if result["best_epoch"] >= 0 else 1)
 
 def _run_phase(root: str, phase: str, epochs: int) -> dict:
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
+    env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(root, "cc")
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "true"
     if "xla_force_host_platform_device_count" not in \
             env.get("XLA_FLAGS", ""):
         env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
@@ -94,7 +102,6 @@ def main() -> int:
         res["hits"] = stamp.get("hits")
         res["misses"] = stamp.get("misses")
         res["fallback_steps"] = stamp.get("fallback_steps")
-        res["washes"] = stamp.get("washes")
         print(json.dumps(dict(res, metric="drill_warmstart")))
     if len(stamps) == 3:
         cold, requeue, replay = results
@@ -107,9 +114,6 @@ def main() -> int:
             if warm["fallback_steps"]:
                 failures.append(f"{warm['phase']} fell back "
                                 f"{warm['fallback_steps']} step(s)")
-            if not warm["washes"]:
-                failures.append(f"{warm['phase']} never washed the "
-                                "restored state")
         summary = {
             "metric": "drill_warmstart_summary",
             "status": "FAIL" if failures else "PASS",

@@ -159,6 +159,34 @@ def parse_slurm_env(env: Mapping[str, str]) -> SlurmEnv | None:
     )
 
 
+def canonical_backend(backend: str | None) -> str | None:
+    """Operator-compat mapping for the reference's flag values
+    (``imagenet.py:440``, invoked as ``--backend=nccl`` at
+    ``imagenet.sh:26``): nccl = "the accelerator fabric" -> TPU
+    runtime; gloo = "CPU fallback" -> cpu."""
+    return {"nccl": "tpu", "gloo": "cpu"}.get(backend, backend)
+
+
+def require_backend(backend: str | None) -> None:
+    """The platform JAX actually initialized must be the one that was
+    asked for. ``--backend=tpu`` with no chip attached used to carry on
+    silently on the CPU; now it is a fatal-config refusal (ValueError
+    -> exit 78) that names what was found. Initializes the backend."""
+    backend = canonical_backend(backend)
+    if not backend:
+        return
+    found = jax.devices()[0]
+    if found.platform != backend:
+        raise ValueError(
+            f"--backend={backend} was requested but JAX initialized the "
+            f"{found.platform!r} platform ({found.device_kind} x"
+            f"{jax.device_count()}; JAX_PLATFORMS="
+            f"{os.environ.get('JAX_PLATFORMS', '')!r}). Refusing to "
+            f"run on a platform that was not asked for: attach the "
+            f"{backend} device, or pass --backend={found.platform} to "
+            "run there on purpose.")
+
+
 def initialize(backend: str | None = None,
                env: Mapping[str, str] | None = None,
                port: int | None = None,
@@ -185,17 +213,15 @@ def initialize(backend: str | None = None,
     ``exitcodes.ElasticExcludedError`` when the roster committed
     without this host.
     """
-    # Operator-compat mapping for the reference's flag values
-    # (``imagenet.py:440``, invoked as ``--backend=nccl`` at
-    # ``imagenet.sh:26``): nccl = "the accelerator fabric" -> TPU
-    # runtime; gloo = "CPU fallback" -> cpu.
-    backend = {"nccl": "tpu", "gloo": "cpu"}.get(backend, backend)
+    backend = canonical_backend(backend)
     if backend and backend != "tpu":
-        # Force the requested platform. "tpu" deliberately leaves the
-        # runtime's own accelerator auto-selection in place (the TPU
-        # plugin's registered name varies across runtimes); "cpu"/"gpu"
-        # must win even over an environment-preset JAX_PLATFORMS — both in
-        # this process (jax.config) and in spawned workers (env var).
+        # Force the requested platform: "cpu"/"gpu" must win even over
+        # an environment-preset JAX_PLATFORMS — both in this process
+        # (jax.config) and in spawned workers (env var). "tpu" forces
+        # nothing here (JAX selects the TPU itself wherever one is
+        # attached); ``require_backend`` then REFUSES whatever else the
+        # runtime fell back to, so --backend=tpu can never quietly
+        # train on the CPU.
         os.environ["JAX_PLATFORMS"] = backend
         jax.config.update("jax_platforms", backend)
     environ = env if env is not None else os.environ
@@ -210,11 +236,8 @@ def initialize(backend: str | None = None,
             # implemented on the CPU backend". Must be set before the
             # backend initializes; harmless for single-process runs
             # (guarded by world_size above).
-            try:
-                jax.config.update("jax_cpu_collectives_implementation",
-                                  "gloo")
-            except Exception:
-                pass  # older/newer jax without the option: leave as-is
+            jax.config.update("jax_cpu_collectives_implementation",
+                              "gloo")
         if port is None:
             # Two jobs sharing a login host must not collide on the
             # fixed reference port (MASTER_PORT 29500, imagenet.py:242).
@@ -257,11 +280,8 @@ def initialize(backend: str | None = None,
                 # is rejected by make_cpu_client ("Unknown collectives
                 # implementation None"), which turned every shrink-to-
                 # one restart into a backend-init crash (exit 70).
-                try:
-                    jax.config.update(
-                        "jax_cpu_collectives_implementation", "none")
-                except Exception:
-                    pass
+                jax.config.update(
+                    "jax_cpu_collectives_implementation", "none")
             return senv
         jax.distributed.initialize(
             coordinator_address=f"{senv.coordinator}:{port}",
@@ -313,12 +333,19 @@ def make_mesh(model_parallel: int = 1,
         # (model, pipe) axes on physically adjacent chips so their
         # collectives take single ICI hops; correctness never depends on
         # the order (batch rows may land on any device), only locality.
+        from jax.experimental import mesh_utils
         try:
-            from jax.experimental import mesh_utils
             return Mesh(mesh_utils.create_device_mesh(shape),
                         (DATA_AXIS, PIPE_AXIS, MODEL_AXIS))
-        except (ImportError, ValueError, AssertionError):
-            pass  # unusual topology: fall through to the naive order
+        except (ValueError, AssertionError) as e:
+            # Unusual topology: fall through to the naive order — and
+            # say so, because the inner axes may now span slow links.
+            if jax.process_index() == 0:
+                print(f"WARNING: topology-aware device mesh unavailable "
+                      f"for shape {shape} ({type(e).__name__}: {e}); "
+                      "using the naive jax.devices() order — "
+                      "model/pipe-axis collectives may not ride "
+                      "adjacent chips", flush=True)
     grid = devs.reshape(shape)
     return Mesh(grid, (DATA_AXIS, PIPE_AXIS, MODEL_AXIS))
 

@@ -14,17 +14,25 @@ capture.  This module closes both gaps:
   for ``cost_analysis()``/``memory_analysis()`` (``build_account``'s
   ``compiled_train=``/``compiled_eval=`` handoff), so its
   ``capture_s`` collapses to ~0.
-* **Persistent executable store**: where the runtime supports
-  ``jax.experimental.serialize_executable``, the compiled products are
-  serialized under ``<--compile-cache>/aot/<key>/`` keyed by a COMPLETE
-  compile fingerprint — device kind + count, mesh topology, world
-  size, jax/jaxlib versions, global batch/accum, and every config
-  field that reaches the step builders (``COMPILE_FIELDS``, pinned by
-  the completeness guard in ``tests/test_compilecache.py``).  A
-  restarted / requeued / resized-to-a-seen-topology run deserializes
-  instead of recompiling; the XLA persistent cache dir (the classic
-  ``--compile-cache`` behavior) remains the second line of defense
-  for everything else that compiles.
+* **Persistent executable store**: the compiled products are
+  serialized (``jax.experimental.serialize_executable``) under
+  ``<cache dir>/aot/<key>/`` keyed by a COMPLETE compile fingerprint —
+  device kind + count, mesh topology, world size, jax/jaxlib versions,
+  global batch/accum, and every config field that reaches the step
+  builders (``COMPILE_FIELDS``, pinned by the completeness guard in
+  ``tests/test_compilecache.py``).  A restarted / requeued /
+  resized-to-a-seen-topology run deserializes instead of recompiling;
+  the XLA persistent cache in the same directory remains the second
+  line of defense for everything else that compiles.
+* **One place for the cache** (``resolve_cache_dir`` / ``arm``): where
+  ``JAX_COMPILATION_CACHE_DIR`` is set, THAT directory is the cache
+  and this code sets no other; where it is not, the cache is the fixed
+  git-ignored ``<checkout>/.jax_cache`` — the path is part of XLA's
+  cache key, so a directory that moves (mkdtemp, pid, time) never
+  hits.  The engine, ``compilecache warm``, ``bench.py``,
+  ``chip_smoke.py`` and the benchmarks all come through here; JAX's
+  own switch (``JAX_ENABLE_COMPILATION_CACHE=false``) turns both
+  halves off (the test suite does, for hermetic compiles).
 * **Dispatch safety**: AOT executables are shape/dtype-specialized,
   but the fault drills deliberately change batch geometry mid-run
   (``step.shape_change`` crops, ``nan-grads`` promotes uint8→f32).
@@ -32,22 +40,16 @@ capture.  This module closes both gaps:
   compares, ~µs) and falls back to the never-yet-traced jitted twin on
   mismatch — one counted retrace, exactly the semantics the recompile
   sentinel drills pin.
-* **The jax<0.5 segfault fence**: the persistent XLA cache could
-  segfault on older runtimes when a cached executable was reloaded
-  (skipped since PR 1).  ``probe`` exercises the full write→reload→
-  serialize→deserialize cycle in throwaway SUBPROCESSES — a crash
-  kills the probe child, not the run — and caches the verdict in
-  ``<cache_dir>/probe.json`` keyed by (jax, jaxlib, platform).  A
-  failed probe downgrades loudly: WARN + cold compile, never a crash.
 
-``python -m imagent_tpu.compilecache ls|prune|warm <cache_dir>`` is
-the operator CLI; ``make drill-warmstart`` measures the warm-vs-cold
-restart wall time this module buys.
+``python -m imagent_tpu.compilecache ls|prune|warm`` is the operator
+CLI (the cache directory argument defaults to the resolved one);
+``make drill-warmstart`` measures the warm-vs-cold restart wall time
+this module buys.
 
 Module import is **jax-free** (manifest: ``analysis/jaxfree.json``) —
-the CLI's ls/prune and the fingerprint math must run on any login
-node; every jax touch is lazy inside ``compile_steps``/``probe``'s
-child and the ``warm`` subcommand.
+the CLI's ls/prune, the resolver and the fingerprint math must run on
+any login node; every jax touch is lazy inside ``arm`` /
+``compile_steps`` and the ``warm`` subcommand.
 """
 
 from __future__ import annotations
@@ -56,9 +58,55 @@ import hashlib
 import json
 import os
 import pickle
-import subprocess
 import sys
 import time
+
+# ---------------------------------------------------------------------------
+# Where the cache lives
+# ---------------------------------------------------------------------------
+
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+# The fixed fallback: inside the checkout (git-ignored), never a
+# temporary, pid- or time-derived path — XLA keys its cache on the
+# directory, so a cache that moves is a cache that never hits.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
+
+
+def resolve_cache_dir() -> str:
+    """THE compile-cache directory (XLA disk cache, ``aot/`` store
+    beneath it): ``$JAX_COMPILATION_CACHE_DIR`` where set, else the
+    fixed in-checkout path.  Pure path arithmetic — jax-free."""
+    d = os.environ.get(CACHE_DIR_ENV, "").strip()
+    return os.path.abspath(d) if d else DEFAULT_CACHE_DIR
+
+
+def arm() -> str | None:
+    """Point JAX's persistent compilation cache at the resolved
+    directory and return it — or None where JAX's own switch
+    (``jax_enable_compilation_cache`` /
+    ``JAX_ENABLE_COMPILATION_CACHE=false``) turned persistent caching
+    off, in which case the ``aot/`` store stays off with it.
+
+    The only ``jax_compilation_cache_dir`` update in the tree: with
+    the environment variable set, JAX already holds that value and
+    nothing is written; unset, the fixed default is.  A process whose
+    cache was already initialized elsewhere (a test session moving
+    between directories) is re-pointed via ``reset_cache``."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    if not jax.config.jax_enable_compilation_cache:
+        return None
+    d = resolve_cache_dir()
+    if jax.config.jax_compilation_cache_dir != d:
+        jax.config.update("jax_compilation_cache_dir", d)
+        compilation_cache.reset_cache()
+    os.makedirs(d, exist_ok=True)
+    return d
+
 
 # ---------------------------------------------------------------------------
 # The compile fingerprint
@@ -280,124 +328,6 @@ class ExecutableStore:
 
 
 # ---------------------------------------------------------------------------
-# The capability probe (the jax<0.5 segfault fence)
-# ---------------------------------------------------------------------------
-
-PROBE_FILENAME = "probe.json"
-
-# Two child passes over one scratch cache dir.  The "write" pass
-# exercises a persistent-cache WRITE plus the serialize →
-# deserialize_and_load → execute cycle on a COLD-compiled executable
-# (the store's save/load path).  The "reload" pass then re-jits the
-# same program so XLA loads it from the disk cache and executes — the
-# exact cycle that segfaulted older CPU runtimes.  The reload pass
-# deliberately does NOT serialize: a cache-loaded executable can
-# serialize to a payload whose kernel symbols don't resolve
-# ("Symbols not found" on deserialize) — the store treats such a blob
-# as a miss at load time, so it is a non-capability, not a hazard.
-# Any crash (segfault, abort, assertion) kills the child; the parent
-# reads an exit code, never shares the fate.
-_PROBE_CHILD = r"""
-import sys
-import jax
-import jax.numpy as jnp
-jax.config.update("jax_compilation_cache_dir", sys.argv[1])
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-f = jax.jit(lambda x: (x * 2.0 + 1.0).sum())
-assert float(f(jnp.arange(8.0))) == 64.0
-if sys.argv[2] == "write":
-    from jax.experimental import serialize_executable as _se
-    c = jax.jit(lambda x: x * 3.0).lower(jnp.arange(4.0)).compile()
-    payload, in_tree, out_tree = _se.serialize(c)
-    c2 = _se.deserialize_and_load(payload, in_tree, out_tree)
-    assert float(c2(jnp.arange(4.0))[1]) == 3.0
-    # The engine's dispatch contract for LOADED executables with
-    # input donation: host-committed (device_put) arguments are
-    # washed through an optimization_barrier copy first (see
-    # wash_state).  Verify that cycle computes exactly — a runtime
-    # where even the washed path miscomputes must fail the probe
-    # and downgrade to cold compiles.
-    import numpy as _np
-    from jax import lax as _lax
-    g = jax.jit(lambda s, x: (s + x, (s * x).sum()),
-                donate_argnums=0)
-    cg = g.lower(jnp.zeros(8, jnp.float32),
-                 jnp.ones(8, jnp.float32)).compile()
-    pg = _se.serialize(cg)
-    del cg
-    lg = _se.deserialize_and_load(*pg)
-    wash = jax.jit(lambda t: _lax.optimization_barrier(t))
-    s0 = wash(jax.device_put(_np.arange(8.0, dtype=_np.float32)))
-    _out_s, out_v = lg(s0, jnp.ones(8, jnp.float32))
-    assert float(out_v) == 28.0, float(out_v)
-print("probe ok")
-"""
-
-# Bumped when the probe child gains new checks: a cached verdict from
-# an older probe no longer vouches for the current contract.
-PROBE_VERSION = 2
-
-
-def probe_token() -> dict:
-    """What the cached probe verdict is keyed on — a runtime change
-    (upgraded jax/jaxlib, different platform selection) re-probes."""
-    import importlib.metadata as md
-
-    def ver(pkg: str) -> str:
-        try:
-            return md.version(pkg)
-        except Exception:  # noqa: BLE001 - vendored installs
-            return "?"
-
-    return {"jax": ver("jax"), "jaxlib": ver("jaxlib"),
-            "platforms": os.environ.get("JAX_PLATFORMS", ""),
-            "probe": PROBE_VERSION}
-
-
-def probe(cache_dir: str, timeout_s: float = 180.0,
-          force: bool = False) -> tuple[bool, str]:
-    """(ok, detail) — is the persistent cache + executable
-    serialization cycle safe on this runtime?  The verdict is cached
-    in ``<cache_dir>/probe.json`` keyed by ``probe_token`` so the
-    subprocess cost (~2 trivial jax startups) is paid once per cache
-    dir per runtime, not per engine start."""
-    from imagent_tpu.telemetry.events import read_json, \
-        write_json_atomic
-
-    os.makedirs(cache_dir, exist_ok=True)
-    path = os.path.join(cache_dir, PROBE_FILENAME)
-    token = probe_token()
-    rec = read_json(path)
-    if not force and rec is not None and rec.get("token") == token:
-        return bool(rec.get("ok")), str(rec.get("detail", "cached"))
-    scratch = os.path.join(cache_dir, ".probe_scratch")
-    os.makedirs(scratch, exist_ok=True)
-    ok, detail = True, "write+reload+serialize cycle ok"
-    for attempt in ("write", "reload"):
-        try:
-            proc = subprocess.run(
-                [sys.executable, "-c", _PROBE_CHILD, scratch, attempt],
-                capture_output=True, text=True, timeout=timeout_s)
-        except subprocess.TimeoutExpired:
-            ok, detail = False, f"probe child timed out ({attempt})"
-            break
-        if proc.returncode != 0:
-            tail = (proc.stderr or proc.stdout or "").strip()
-            tail = tail[-300:] if tail else "no output"
-            ok = False
-            detail = (f"probe child died rc={proc.returncode} on the "
-                      f"{attempt} pass: {tail}")
-            break
-    try:
-        write_json_atomic(path, {"token": token, "ok": ok,
-                                 "detail": detail,
-                                 "t": round(time.time(), 3)})
-    except OSError:
-        pass  # unverdicted next time; the answer stands for this run
-    return ok, detail
-
-
-# ---------------------------------------------------------------------------
 # The dispatch wrapper
 # ---------------------------------------------------------------------------
 
@@ -406,34 +336,6 @@ def batch_signature(args: tuple) -> tuple:
     """((shape, dtype), ...) over the batch args — the per-call
     compatibility check's expected value."""
     return tuple((tuple(a.shape), str(a.dtype)) for a in args)
-
-
-def wash_state(state):
-    """Copy every leaf of ``state`` through a jitted
-    ``lax.optimization_barrier`` so the buffers come out as XLA
-    executable outputs.
-
-    jax<0.5 CPU: a DESERIALIZED executable with input donation
-    miscomputes — metrics read as zeros/NaN, param reads land in
-    freed or foreign memory — when the donated argument holds
-    host-committed ``device_put`` buffers, exactly what checkpoint
-    restore (``place_state`` on numpy leaves) and torch-weight
-    import produce.  The same executable is bit-exact on buffers
-    that came out of any XLA computation, and a cold-compiled
-    executable is immune either way (isolated deterministically:
-    12/12 donated+device_put trials wrong, 12/12 undonated or
-    washed trials exact).  The engine therefore washes any
-    restored/imported state before it can reach a hit-loaded
-    executable, and the probe's write pass verifies this washed
-    cycle computes exactly on a toy donated executable.
-
-    The barrier — rather than ``x + 0`` — is dtype-agnostic (bool
-    and integer leaves included) and can be neither folded away by
-    XLA nor input-forwarded by jax, so the copy is guaranteed."""
-    import jax
-    from jax import lax
-
-    return jax.jit(lambda t: lax.optimization_barrier(t))(state)
 
 
 class CompiledStep:
@@ -493,6 +395,7 @@ def compile_steps(*, train_step, eval_step, state, mesh, cfg,
     import numpy as np
 
     import jax
+    from jax.experimental import serialize_executable as serexe
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from imagent_tpu.telemetry import chipacct as chipacct_lib
@@ -503,7 +406,7 @@ def compile_steps(*, train_step, eval_step, state, mesh, cfg,
         "store": store.root if store is not None else None,
         "hits": 0, "misses": 0, "saved": 0,
         "compile_s": 0.0, "load_s": 0.0,
-        "fallback_steps": 0, "washes": 0,
+        "fallback_steps": 0,
     }
     lr_sds = jax.ShapeDtypeStruct(
         (), np.float32, sharding=NamedSharding(mesh, P()))
@@ -516,16 +419,11 @@ def compile_steps(*, train_step, eval_step, state, mesh, cfg,
     if eval_step is not None:
         plans.append(("eval", eval_step, (state, *ev)))
 
-    try:
-        from jax.experimental import serialize_executable as serexe
-    except Exception:  # noqa: BLE001 - runtimes without the API
-        serexe = None
-
     wrappers: dict = {"train": None, "eval": None}
     compiled_objs: dict = {"train": None, "eval": None}
     for name, jitted, args in plans:
         compiled = None
-        if store is not None and serexe is not None:
+        if store is not None:
             triple = store.load(key, name, rank, world)
             if triple is not None:
                 t0 = time.perf_counter()
@@ -541,7 +439,7 @@ def compile_steps(*, train_step, eval_step, state, mesh, cfg,
             t0 = time.perf_counter()
             compiled = jitted.lower(*args).compile()
             stats["compile_s"] += time.perf_counter() - t0
-            if store is not None and serexe is not None:
+            if store is not None:
                 try:
                     triple = serexe.serialize(compiled)
                     if store.save(key, fp, name, rank, world, triple):
@@ -561,9 +459,9 @@ def compile_steps(*, train_step, eval_step, state, mesh, cfg,
 def plan_line(stats: dict) -> str:
     """The startup plan print (master only) — the warm drill and
     bench-smoke stage 6 assert the hit/miss counters appear here."""
-    src = ("serialized store + XLA disk cache" if stats.get("store")
-           else "XLA disk cache only"
-           if stats.get("xla_cache") else "in-memory only")
+    src = (f"serialized store + XLA disk cache under "
+           f"{os.path.dirname(stats['store'])}" if stats.get("store")
+           else "persistent cache OFF (jax_enable_compilation_cache)")
     return (f"compile cache: key {stats.get('key')} — "
             f"{stats.get('hits', 0)} hit(s), "
             f"{stats.get('misses', 0)} compiled, "
@@ -605,20 +503,13 @@ def _cli_ls(cache_dir: str) -> int:
     try:
         for ent in os.listdir(cache_dir):
             p = os.path.join(cache_dir, ent)
-            if ent in ("aot", PROBE_FILENAME, ".probe_scratch") \
-                    or not os.path.isfile(p):
+            if ent == "aot" or not os.path.isfile(p):
                 continue
             n += 1
             nbytes += os.stat(p).st_size
     except OSError:
         pass
     print(f"  xla disk cache: {n} file(s), {_fmt_mb(nbytes)}")
-    from imagent_tpu.telemetry.events import read_json
-    rec = read_json(os.path.join(cache_dir, PROBE_FILENAME))
-    if rec is not None:
-        verdict = "ok" if rec.get("ok") else "UNSAFE (fenced)"
-        print(f"  probe: {verdict} — {rec.get('detail')} "
-              f"[jax {((rec.get('token') or {}).get('jax'))}]")
     return 0
 
 
@@ -632,27 +523,28 @@ def _cli_prune(cache_dir: str, older_days: float | None,
     return 0
 
 
-def _cli_warm(cache_dir: str, engine_argv: list[str]) -> int:
+def _cli_warm(engine_argv: list[str]) -> int:
     """Pre-populate the cache for a config WITHOUT training: build the
     mesh/model/steps exactly as the engine would (the shared
     ``_build_model_and_steps``) and run ``compile_steps`` against the
-    store — a scheduler can warm a topology before the pod lands."""
+    store — a scheduler can warm a topology before the pod lands. The
+    cache is the resolved one (``JAX_COMPILATION_CACHE_DIR`` or the
+    in-checkout default), same as the run it warms."""
     from imagent_tpu.config import parse_args
 
     cfg = parse_args(engine_argv)
-    ok, detail = probe(os.path.abspath(cache_dir))
-    if not ok:
-        print(f"warm: REFUSED — probe verdict: {detail}", flush=True)
-        return 1
     import jax
 
     from imagent_tpu import cluster
     from imagent_tpu import engine as engine_lib
 
-    jax.config.update("jax_compilation_cache_dir",
-                      os.path.abspath(cache_dir))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                      1.0)
+    cluster.initialize(cfg.backend or None)
+    cluster.require_backend(cfg.backend or None)
+    cache_dir = arm()
+    if cache_dir is None:
+        print("warm: REFUSED — the persistent cache is switched off "
+              "(jax_enable_compilation_cache)", flush=True)
+        return 1
     mesh = cluster.make_mesh(cfg.model_parallel,
                              pipeline_parallel=cfg.pipeline_parallel)
     n_data = mesh.shape[cluster.DATA_AXIS]
@@ -665,8 +557,7 @@ def _cli_warm(cache_dir: str, engine_argv: list[str]) -> int:
     train_step, eval_step, state, _specs = \
         engine_lib._build_model_and_steps(cfg, mesh, n_data, accum,
                                           is_master=True)
-    store = ExecutableStore(os.path.join(os.path.abspath(cache_dir),
-                                         "aot"))
+    store = ExecutableStore(os.path.join(cache_dir, "aot"))
     fp = fingerprint(cfg, mesh_shape=dict(mesh.shape),
                      global_batch=global_batch, accum=accum,
                      runtime=runtime_facts())
@@ -685,13 +576,16 @@ def main(argv=None) -> int:
     p = argparse.ArgumentParser(
         prog="python -m imagent_tpu.compilecache",
         description="Persistent AOT executable cache: list, prune, or "
-                    "pre-warm a --compile-cache directory")
+                    "pre-warm the compile cache "
+                    f"(${CACHE_DIR_ENV}, else {DEFAULT_CACHE_DIR})")
     sub = p.add_subparsers(dest="cmd", required=True)
     ls = sub.add_parser("ls", help="list cached executables + the XLA "
                                    "disk-cache footprint")
-    ls.add_argument("cache_dir")
     pr = sub.add_parser("prune", help="drop cached executables")
-    pr.add_argument("cache_dir")
+    for cmd in (ls, pr):
+        cmd.add_argument("cache_dir", nargs="?", default=None,
+                         help="cache to inspect (default: the "
+                              "resolved one)")
     pr.add_argument("--older-than-days", type=float, default=None,
                     metavar="D",
                     help="drop entries whose newest executable is "
@@ -700,18 +594,18 @@ def main(argv=None) -> int:
                     help="drop exactly this fingerprint key")
     warm = sub.add_parser(
         "warm", help="compile + serialize a config's step executables "
-                     "into the cache without training (engine flags "
-                     "after --)")
-    warm.add_argument("cache_dir")
+                     "into the resolved cache without training "
+                     "(engine flags after --)")
     warm.add_argument("engine_args", nargs="*",
                       help="engine flags, e.g. --arch resnet50 "
                            "--image-size 224")
     ns = p.parse_args(argv)
+    if ns.cmd == "warm":
+        return _cli_warm(list(ns.engine_args))
+    cache_dir = ns.cache_dir or resolve_cache_dir()
     if ns.cmd == "ls":
-        return _cli_ls(ns.cache_dir)
-    if ns.cmd == "prune":
-        return _cli_prune(ns.cache_dir, ns.older_than_days, ns.key)
-    return _cli_warm(ns.cache_dir, list(ns.engine_args))
+        return _cli_ls(cache_dir)
+    return _cli_prune(cache_dir, ns.older_than_days, ns.key)
 
 
 if __name__ == "__main__":

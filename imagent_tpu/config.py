@@ -140,13 +140,12 @@ class Config:
     # step-time p95 exceeds this multiple of the pod median (see
     # telemetry/aggregate.py for the absolute floors).
     straggler_factor: float = 2.0
-    # Persistent XLA compilation cache dir ("" = off): restarted/resumed
-    # runs skip the first-step compile (~minutes for big models).
-    compile_cache: str = ""
     # One-compile AOT startup (compilecache.py): compile each step
     # executable once via lower().compile(), share it with the chip
-    # accountant, and (with --compile-cache) serialize it for warm
-    # restarts. False = legacy jit-on-first-step.
+    # accountant, and serialize it into the compile cache
+    # ($JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache —
+    # compilecache.resolve_cache_dir) for warm restarts. False =
+    # legacy jit-on-first-step.
     aot_steps: bool = True
     check_nans: bool = False  # debug flag (SURVEY §5 sanitizers)
     # Asynchronous per-epoch LAST checkpointing (checkpoint.save_async):
@@ -504,11 +503,6 @@ def build_parser() -> argparse.ArgumentParser:
                    default=c.straggler_factor,
                    help="flag a host whose input-wait or step p95 "
                         "exceeds this multiple of the pod median")
-    p.add_argument("--compile-cache", type=str, default=c.compile_cache,
-                   help="persistent XLA compilation cache directory "
-                        "(also arms the serialized AOT executable "
-                        "store under <dir>/aot — see "
-                        "python -m imagent_tpu.compilecache)")
     p.add_argument("--no-aot-steps", dest="aot_steps",
                    action="store_false", default=c.aot_steps,
                    help="disable the one-compile AOT startup path "

@@ -1,7 +1,9 @@
 """Decode offload: ship JPEG decode to non-training CPU hosts.
 
-BENCH_r05's 14.4× per-chip rate for r18@448 makes host JPEG decode the
-wall (ROADMAP item 5): a TPU host has a fixed CPU budget, and past it
+A chip that trains r18@448 an order of magnitude faster than the
+reference's GPUs makes host JPEG decode the wall (ROADMAP item 5; the
+per-chip rate itself is not measured on the current code): a TPU host
+has a fixed CPU budget, and past it
 the chips starve however many ``--workers`` are configured. This module
 moves the decode OFF the training hosts: any number of plain CPU boxes
 run ``python -m imagent_tpu.data.serve`` against the same dataset

@@ -136,11 +136,14 @@ class _LaggedMetrics:
     consumed ``lag`` steps BEHIND the dispatch.
 
     This is what makes the epoch boundary drain-free: every fetch
-    (``np.asarray``) targets a vector whose step has (almost always)
-    already retired — a cheap D2H of 16 ready bytes, never a pipeline
-    drain — and by the time the epoch ends only the ≤ ``lag``-step tail
-    remains unconsumed, so ``drain()`` waits on the in-flight frontier
-    tail, not on transferring a whole epoch of buffered vectors. The
+    (``np.asarray``) targets a vector ``lag`` steps old — a D2H of 16
+    bytes, never a pipeline drain — and by the time the epoch ends only
+    the ≤ ``lag``-step tail remains unconsumed, so ``drain()`` waits on
+    the in-flight frontier tail, not on transferring a whole epoch of
+    buffered vectors. Dispatch is asynchronous, so on a device-bound
+    run these fetches are where the host waits for the chip — the
+    waits are summed in ``wait_s`` (and, with ``wait_span`` named,
+    traced as phase spans) for the goodput ``step_drain`` phase. The
     non-finite step guard (``bad``/``tripped``) and the ``--log-every``
     readout (``last``) ride the same consumed stream, so the step loop
     body itself contains NO blocking call on an in-flight result (the
@@ -151,9 +154,13 @@ class _LaggedMetrics:
                  is_master: bool = False,
                  health: HealthMonitor | None = None,
                  health_rollback: bool = False, epoch: int = 0,
-                 start_step: int = 0):
+                 start_step: int = 0, wait_span: str | None = None):
         self._pending: collections.deque = collections.deque()
         self.lag = lag
+        # Host seconds blocked on not-yet-retired vectors (lagged reads
+        # + the tail drain); wait_span names their trace phase spans.
+        self.wait_s = 0.0
+        self._wait_span = wait_span
         self.max_bad = max_bad
         self.is_master = is_master
         # Model-health tail: vectors longer than the classic 4-field
@@ -173,7 +180,15 @@ class _LaggedMetrics:
         self.last: np.ndarray | None = None  # newest consumed vector
 
     def _consume(self, m) -> None:
+        t0 = time.perf_counter()
         v = np.asarray(m)
+        waited = time.perf_counter() - t0
+        self.wait_s += waited
+        if (self._wait_span is not None
+                and waited > trace_lib.MIN_WAIT_SPAN_S):
+            # (a no-op without an active tracer)
+            trace_lib.complete(self._wait_span, t0, t0 + waited,
+                               cat=trace_lib.PHASE_CAT)
         self._sums += v[:4]
         self.steps += 1
         self.last = v
@@ -331,7 +346,8 @@ def train_one_epoch(cfg: Config, mesh, train_step, state: TrainState,
     acc = _LaggedMetrics(max_bad=max(cfg.max_bad_steps, 0),
                          is_master=is_master, health=health,
                          health_rollback=cfg.health_rollback,
-                         epoch=epoch, start_step=start_step)
+                         epoch=epoch, start_step=start_step,
+                         wait_span="step_drain")
     rollback = False
 
     if prefetch is not None:
@@ -363,6 +379,14 @@ def train_one_epoch(cfg: Config, mesh, train_step, state: TrainState,
                 break
             data_time.update(time.time() - t_fetch)
             images, labels = arrays
+            if i == 0 and telem is not None:
+                # Where the staged batch really lives (sharding
+                # metadata, no sync): every data-axis device should
+                # hold its own rows.
+                shards = images.addressable_shards
+                telem.gauge("batch_shard_devices",
+                            len({s.device.id for s in shards}))
+                telem.gauge("batch_shard_rows", shards[0].data.shape[0])
             lr_step = lr_arr
             if faultinject.active():  # drills only; falsy no-op otherwise
                 f = faultinject.fire("step.grad_spike")
@@ -509,7 +533,6 @@ def train_one_epoch(cfg: Config, mesh, train_step, state: TrainState,
             and epoch + 1 < cfg.epochs):
         warm = Prefetcher(mesh, loader.epoch(epoch + 1),
                           depth=cfg.prefetch_depth)
-    t_drain = time.perf_counter()
     # Drain the ≤ _GUARD_LAG-step in-flight tail (not a sync). A trip
     # discovered here — the guard's or the health detector's — counts
     # only for a completed epoch; a preemption exit keeps the
@@ -525,9 +548,10 @@ def train_one_epoch(cfg: Config, mesh, train_step, state: TrainState,
     # the diverging updates, unlike guard-skipped ones, WERE applied.
     epoch_metrics["health_rollback"] = bool(acc.health_tripped)
     if telem is not None:
-        # The drain wait is the device retiring the dispatched frontier
-        # tail — the device-side tail of useful training work.
-        telem.phase("step_drain", time.perf_counter() - t_drain)
+        # Every wait on the frontier — the lagged reads and the tail
+        # drain — is the host waiting for the device to retire
+        # dispatched steps: the device side of useful training work.
+        telem.absorb_step_wait(acc.wait_s)
         telem.absorb_input(stats)
         telem.count("quarantined",
                     int(getattr(loader, "quarantined", 0) or 0))
@@ -837,8 +861,10 @@ def run(cfg: Config, stop_check=None) -> dict:
     if cfg.slo not in ("", "off") and not cfg.telemetry:
         raise ValueError("--slo evaluates the telemetry epoch record; "
                          "drop --no-telemetry")
-    # cfg.backend selects the PJRT platform: "tpu" = runtime auto-select;
-    # "cpu"/"gpu" are forced, overriding any environment preset.
+    # cfg.backend selects the PJRT platform: "cpu"/"gpu" are forced,
+    # overriding any environment preset; "tpu" is what JAX selects
+    # wherever a chip is attached — and require_backend below refuses
+    # (fatal-config, exit 78) anything else the runtime fell back to.
     # --elastic: membership comes from the filesystem rendezvous (the
     # roster of processes that actually showed up), not the scheduler
     # env — a requeued pod missing a host re-forms at N-1 instead of
@@ -857,6 +883,7 @@ def run(cfg: Config, stop_check=None) -> dict:
             elastic_settle=cfg.elastic_settle_secs,
             group_size=group_size_hint)
     senv = cluster.initialize(cfg.backend or None, **elastic_kw)
+    cluster.require_backend(cfg.backend or None)
     # Real (post-init) group size. A wrong IMAGENT_LOCAL_DEVICES hint
     # under --elastic means the roster was committed against the wrong
     # group map — refuse loudly rather than shrink by the wrong stride.
@@ -1415,27 +1442,12 @@ def _build_model_and_steps(cfg, mesh, n_data: int, accum: int,
 
 def _run(cfg: Config, stop_check, senv, watchdog, pod=None,
          recorder=None) -> dict:
-    # The jax<0.5 persistent-cache segfault fence (compilecache.probe):
-    # the full write→reload→serialize cycle runs in throwaway
-    # subprocesses before the cache dir is armed — a runtime that
-    # would crash downgrades to cold compiles with a loud WARN instead
-    # of taking the pod down. Verdict cached per (jax, jaxlib,
-    # platform) in <dir>/probe.json, so steady-state restarts pay a
-    # file read.
-    cc_probe_ok = False
-    if cfg.compile_cache:
-        cc_probe_ok, probe_detail = compilecache_lib.probe(
-            os.path.abspath(cfg.compile_cache))
-        if cc_probe_ok:
-            jax.config.update("jax_compilation_cache_dir",
-                              os.path.abspath(cfg.compile_cache))
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 1.0)
-        else:
-            print("WARNING: --compile-cache disabled for this run — "
-                  f"capability probe failed ({probe_detail}); "
-                  "compiles stay cold but the run is safe",
-                  flush=True)
+    # The persistent compile cache (XLA disk cache + the aot/ store
+    # beneath it) lives where JAX_COMPILATION_CACHE_DIR says, else at
+    # the fixed in-checkout path — one resolver for every entry point
+    # (compilecache.arm). None = JAX's own switch
+    # (jax_enable_compilation_cache) turned it off.
+    cache_dir = compilecache_lib.arm()
     print(cluster.rank_banner(senv), flush=True)
     is_master = jax.process_index() == 0
 
@@ -1662,57 +1674,34 @@ def _run(cfg: Config, stop_check, senv, watchdog, pod=None,
     # One-compile startup (compilecache.py): lower+compile each step
     # executable ONCE via the AOT path, dispatch through wrappers that
     # fall back to the jitted twin only when a fault drill changes the
-    # batch geometry, and — when --compile-cache survived the probe —
-    # load/save serialized executables so restarts, requeues and
-    # already-seen elastic topologies start warm. The compiled objects
-    # are handed to the chip accountant below, killing its duplicate
-    # capture compile. Best-effort throughout: any failure WARNs and
-    # falls back to legacy jit-on-first-step (--no-aot-steps forces
-    # that path; eval_only one-shots skip it).
+    # batch geometry, and load/save serialized executables in the
+    # compile cache so restarts, requeues and already-seen elastic
+    # topologies start warm. The compiled objects are handed to the
+    # chip accountant below, killing its duplicate capture compile. A
+    # step that does not compile is the run's failure, raised here —
+    # not a WARN and a second attempt under jit (--no-aot-steps is the
+    # explicit legacy jit-on-first-step path; eval_only one-shots skip
+    # AOT).
     cc_stats = None
     compiled_train = compiled_eval = None
     if cfg.aot_steps and not cfg.eval_only:
-        cc_store = None
-        if cfg.compile_cache and cc_probe_ok:
-            cc_store = compilecache_lib.ExecutableStore(
-                os.path.join(os.path.abspath(cfg.compile_cache), "aot"))
-        try:
-            cc_fp = compilecache_lib.fingerprint(
-                cfg, mesh_shape=dict(mesh.shape),
-                global_batch=global_batch, accum=accum,
-                runtime=compilecache_lib.runtime_facts())
-            aot = compilecache_lib.compile_steps(
-                train_step=train_step, eval_step=eval_step,
-                state=state, mesh=mesh, cfg=cfg,
-                global_batch=global_batch, fp=cc_fp, store=cc_store,
-                rank=jax.process_index(), world=jax.process_count())
-        except Exception as ce:  # noqa: BLE001 - warm path, not the run
-            print(f"WARNING: AOT step compile failed "
-                  f"({type(ce).__name__}: {ce}); falling back to "
-                  "jit-on-first-step", flush=True)
-        else:
-            compiled_train = aot.compiled.get("train")
-            compiled_eval = aot.compiled.get("eval")
-            train_step, eval_step = aot.train, aot.eval
-            cc_stats = aot.stats
-            cc_stats["xla_cache"] = bool(cfg.compile_cache
-                                         and cc_probe_ok)
-            if is_master:
-                print(compilecache_lib.plan_line(cc_stats), flush=True)
-
-    def _wash_if_loaded(st):
-        # jax<0.5: host-committed (device_put) buffers must never
-        # reach a hit-LOADED donated executable — restored/imported
-        # states are copied through an optimization_barrier first
-        # (compilecache.wash_state has the full defect writeup).
-        if cc_stats is not None and cc_stats.get("hits"):
-            cc_stats["washes"] += 1
-            return compilecache_lib.wash_state(st)
-        return st
-
-    # The initial state can hold host-put leaves too (torch-weight
-    # import places numpy arrays); wash it before the first dispatch.
-    state = _wash_if_loaded(state)
+        cc_store = (compilecache_lib.ExecutableStore(
+            os.path.join(cache_dir, "aot")) if cache_dir else None)
+        cc_fp = compilecache_lib.fingerprint(
+            cfg, mesh_shape=dict(mesh.shape),
+            global_batch=global_batch, accum=accum,
+            runtime=compilecache_lib.runtime_facts())
+        aot = compilecache_lib.compile_steps(
+            train_step=train_step, eval_step=eval_step,
+            state=state, mesh=mesh, cfg=cfg,
+            global_batch=global_batch, fp=cc_fp, store=cc_store,
+            rank=jax.process_index(), world=jax.process_count())
+        compiled_train = aot.compiled.get("train")
+        compiled_eval = aot.compiled.get("eval")
+        train_step, eval_step = aot.train, aot.eval
+        cc_stats = aot.stats
+        if is_master:
+            print(compilecache_lib.plan_line(cc_stats), flush=True)
 
     # Chip accountant (telemetry/chipacct.py): XLA cost/memory
     # analyses and the sharding-aware state byte attribution BEFORE
@@ -1832,8 +1821,7 @@ def _run(cfg: Config, stop_check, senv, watchdog, pod=None,
         restored = ckpt_lib.restore_resilient(cfg.ckpt_dir, state)
         if restored is not None:
             state, meta, src = restored
-            state = _wash_if_loaded(
-                place_state(state, mesh, state_specs))
+            state = place_state(state, mesh, state_specs)
             # What was restored, for the status/telemetry surfaces: an
             # emergency salvage or a sharded-format generation must be
             # visibly not a clean Orbax LAST (satellite of the
@@ -2107,6 +2095,7 @@ def _run(cfg: Config, stop_check, senv, watchdog, pod=None,
         # these instead of producing a nonsense verdict. Additions,
         # not a schema bump (consumers ignore unknown keys).
         "device_kind": jax.devices()[0].device_kind,
+        "platform": jax.devices()[0].platform,
         "jax_version": jax.__version__,
         "image_size": cfg.image_size,
         "batch_size": cfg.batch_size,
@@ -2486,8 +2475,7 @@ def _run(cfg: Config, stop_check, senv, watchdog, pod=None,
                     epoch += 1
                     continue
                 state, meta, src = restored
-                state = _wash_if_loaded(
-                    place_state(state, mesh, state_specs))
+                state = place_state(state, mesh, state_specs)
                 telem.phase("recovery", time.perf_counter() - t_rec)
                 # The record names the epoch that FAILED (the one whose
                 # wall time this was), not the replay target below.

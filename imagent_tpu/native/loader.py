@@ -12,6 +12,8 @@ are missing, and callers fall back to the pure-Python (PIL) path.
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -20,7 +22,26 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "io_loader.cc")
-_LIB = os.path.join(_DIR, "libimagent_io.so")
+# -ffp-contract=off: exp_shared/sample_crop must round exactly like
+# the Python port (two roundings per p*f+c, never fused) — GCC's
+# default contraction would emit fma on targets that have it and
+# silently break cross-path augmentation parity. No -march=native —
+# the .so may be shared by heterogeneous hosts.
+_CXXFLAGS = ("-O3", "-fPIC", "-std=c++17", "-ffp-contract=off")
+
+
+def _lib_path() -> str:
+    """The binary is named by the hash of what it was built FROM
+    (source text + flags): a changed source is a different file name,
+    so a stale build — a stray binary copied along with the tree, an
+    older checkout on a shared filesystem — can never be picked up,
+    whatever its mtime says (the ABI check below only catches a
+    changed calling convention, not a changed body)."""
+    h = hashlib.sha256(" ".join(_CXXFLAGS).encode())
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    return os.path.join(_DIR, f"libimagent_io.{h.hexdigest()[:12]}.so")
+
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
@@ -40,18 +61,13 @@ def _abi_version(lib: ctypes.CDLL) -> int:
     return int(fn())
 
 
-def _build() -> bool:
+def _build(lib_path: str) -> bool:
     # Compile to a pid-unique temp path, then os.rename (atomic on POSIX):
     # under multi-process launches on a shared filesystem, concurrent
-    # builders must never let a rank CDLL a half-written .so. No
-    # -march=native — the .so may be shared by heterogeneous hosts.
-    tmp = f"{_LIB}.{os.getpid()}.tmp"
-    # -ffp-contract=off: exp_shared/sample_crop must round exactly like
-    # the Python port (two roundings per p*f+c, never fused) — GCC's
-    # default contraction would emit fma on targets that have it and
-    # silently break cross-path augmentation parity.
-    base = ["g++", "-O3", "-fPIC", "-std=c++17", "-ffp-contract=off",
-            "-shared", "-o", tmp, _SRC, "-ljpeg", "-lpng"]
+    # builders must never let a rank CDLL a half-written .so.
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
+    base = ["g++", *_CXXFLAGS, "-shared", "-o", tmp, _SRC,
+            "-ljpeg", "-lpng"]
     # libwebp is optional: hosts without its headers (common on lean
     # CPU decode boxes) still get the native jpeg/png fast path — webp
     # members fall to the per-file PIL rescue in that build.
@@ -59,10 +75,17 @@ def _build() -> bool:
         try:
             subprocess.run(cmd, check=True, capture_output=True,
                            timeout=120)
-            os.replace(tmp, _LIB)
-            return True
+            os.replace(tmp, lib_path)
         except (subprocess.SubprocessError, FileNotFoundError, OSError):
             continue
+        # Builds of other source versions are dead weight from here on.
+        for old in glob.glob(os.path.join(_DIR, "libimagent_io.*.so")):
+            if old != lib_path:
+                try:
+                    os.unlink(old)
+                except OSError:
+                    pass
+        return True
     try:
         os.unlink(tmp)
     except OSError:
@@ -75,30 +98,19 @@ def _load() -> ctypes.CDLL | None:
     with _lock:
         if _lib is not None or _load_failed:
             return _lib
-        stale = (not os.path.exists(_LIB)
-                 or (os.path.exists(_SRC)
-                     and os.path.getmtime(_SRC) > os.path.getmtime(_LIB)))
-        if stale and not _build():
-            _load_failed = True
-            return None
+        lib = None
         try:
-            lib = ctypes.CDLL(_LIB)
-        except OSError:
+            lib_path = _lib_path()
+            if os.path.exists(lib_path) or _build(lib_path):
+                lib = ctypes.CDLL(lib_path)
+        except OSError:  # unreadable source or unloadable binary
+            lib = None
+        if lib is None or _abi_version(lib) != _ABI_VERSION:
+            # No toolchain/headers, or a source whose il_version()
+            # disagrees with this binding: fail over to the PIL path
+            # rather than corrupting memory.
             _load_failed = True
             return None
-        if _abi_version(lib) != _ABI_VERSION:
-            # Stale binary with a different calling convention (e.g. built
-            # by an older checkout on a shared FS): rebuild once, else fail
-            # over to the PIL path rather than corrupting memory.
-            lib = None
-            if _build():
-                try:
-                    lib = ctypes.CDLL(_LIB)
-                except OSError:
-                    lib = None
-            if lib is None or _abi_version(lib) != _ABI_VERSION:
-                _load_failed = True
-                return None
         lib.il_decode_resize_batch.restype = ctypes.c_int64
         lib.il_decode_resize_batch.argtypes = [
             ctypes.POINTER(ctypes.c_char_p), ctypes.c_int64, ctypes.c_int,

@@ -22,7 +22,9 @@ Design (per the TPU Pallas playbook):
   wrapper and masked inside the kernel by global K position.
 
 Interpret mode (``interpret=True`` on CPU) makes the exact same kernel
-testable on the 8-device CPU mesh used by the test suite.
+testable on the 8-device CPU mesh used by the test suite; what the
+chip's compiler accepts is pinned by ``tests/test_chip_compile.py``
+(``interpret=False`` against a described v5e).
 """
 
 from __future__ import annotations
@@ -32,21 +34,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU-specific pieces are optional so CPU interpret mode still works
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except ImportError:  # pragma: no cover
-    _VMEM = None
+from imagent_tpu.ops import resolve_interpret
 
 _NEG_BIG = -0.7 * float(jnp.finfo(jnp.float32).max)
 _LANES = 128  # m/l scratch stores stats broadcast across one lane tile
-
-
-def _vmem(shape, dtype):
-    if _VMEM is None:  # pragma: no cover
-        return pl.BlockSpec(shape, lambda *_: (0,) * len(shape))
-    return _VMEM(shape, dtype)
 
 
 def _kv_mask(ik, bk, n_real, bq):
@@ -192,9 +185,9 @@ def _flash_fwd_impl(q, k, v, *, block_q, block_k, interpret):
             jax.ShapeDtypeStruct((bh, npad_q, _LANES), jnp.float32),
         ],
         scratch_shapes=[
-            _vmem((block_q, d), jnp.float32),
-            _vmem((block_q, _LANES), jnp.float32),
-            _vmem((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
         interpret=interpret,
     )(qp, kp, vp)
@@ -246,7 +239,7 @@ def _flash_bhd_bwd(block_q, block_k, interpret, res, do):
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, npad_q, d), q.dtype),
-        scratch_shapes=[_vmem((block_q, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
     )(qp, kp, vp, dop, lsep, dip)
 
@@ -271,8 +264,8 @@ def _flash_bhd_bwd(block_q, block_k, interpret, res, do):
             jax.ShapeDtypeStruct((bh, npad_k, d), v.dtype),
         ],
         scratch_shapes=[
-            _vmem((block_k, d), jnp.float32),
-            _vmem((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
     )(qp, kp, vp, dop, lsep, dip)
@@ -287,12 +280,11 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     interpret: bool | None = None) -> jnp.ndarray:
     """Fused attention, drop-in for ``dot_product_attention``.
 
-    Shapes ``(B, N, H, D)`` → ``(B, N, H, D)``. ``interpret=None``
-    auto-selects interpreter mode off-TPU so the same kernel runs in the
-    CPU test mesh.
+    Shapes ``(B, N, H, D)`` → ``(B, N, H, D)``. ``interpret=None``:
+    compiled on the TPU, interpreted on the CPU platform
+    (``ops.resolve_interpret``).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     b, n, h, d = q.shape
     # Clamp to the sequence but keep blocks 8-aligned (TPU sublane tiling);
     # _pad_seq rounds the sequence up to the block, so block==npad is legal.

@@ -43,9 +43,10 @@ Design notes:
   ConvNeXt without droppath rngs (rate 0.0 only — models/convnext.py
   docstring), so an active per-sample drop mask falls back to the
   unfused path (``fused_block_rows`` returns None when ``dropping``).
-* ``interpret=None`` auto-selects interpreter mode off-TPU, so the CPU
-  CI mesh exercises the real kernel code — the ``ops/flash_attention``
-  precedent.
+* ``interpret=None`` compiles on the TPU and interprets on the CPU
+  platform (``ops.resolve_interpret``), so the CPU CI mesh exercises
+  the real kernel code; ``tests/test_chip_compile.py`` compiles it
+  ``interpret=False`` for a described v5e at every fusable width.
 
 ``ops/fused_block.py`` (the rejected ResNet bottleneck fusion) is the
 sibling negative result; this kernel attacks the one geometry the
@@ -62,6 +63,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from imagent_tpu.ops import resolve_interpret
+
 _SQRT2 = math.sqrt(2.0)
 _INV_SQRT_2PI = 1.0 / math.sqrt(2.0 * math.pi)
 _VEC_SUBLANES = 8  # broadcast rows so vector-grad blocks tile on TPU
@@ -72,15 +75,44 @@ VMEM_BUDGET = 12 * 2 ** 20
 _DEFAULT_BLOCK_ROWS = 256
 
 
+# erf has no Pallas TPU lowering in the installed JAX (0.9.0:
+# "Unimplemented primitive ... erf"), so the kernel evaluates it the
+# way XLA itself lowers f32 erf: x * P(x^2) / Q(x^2) on the clamped
+# argument (xla/client/lib/math.cc, ErfImpl32). Max abs error vs
+# ``jax.lax.erf`` in fp32: 3e-7 (one ulp of the result), pinned in
+# tests/test_fused_mlp.py; mul/add/div/clamp all lower to the VPU.
+_ERF_CLAMP = 3.832506856900711
+_ERF_ALPHA = (0.00022905065861350646, 0.0034082910107109506,
+              0.050955695062380861, 0.18520832239976145,
+              1.128379143519084)
+_ERF_BETA = (-1.1791602954361697e-7, 0.000023547966471313185,
+             0.0010179625278914885, 0.014070470171167667,
+             0.11098505178285362, 0.49746925110067538, 1.0)
+
+
+def _horner(x, coeffs):
+    acc = jnp.full_like(x, coeffs[0])
+    for c in coeffs[1:]:
+        acc = acc * x + c
+    return acc
+
+
+def _erf(x):
+    """fp32 erf from primitives the Pallas TPU lowering has."""
+    x = jnp.clip(x, -_ERF_CLAMP, _ERF_CLAMP)
+    x2 = x * x
+    return x * _horner(x2, _ERF_ALPHA) / _horner(x2, _ERF_BETA)
+
+
 def _gelu(a):
     """Exact (erf) GELU in fp32 — matches ``nn.gelu(approximate=False)``."""
-    return 0.5 * a * (1.0 + jax.lax.erf(a / _SQRT2))
+    return 0.5 * a * (1.0 + _erf(a / _SQRT2))
 
 
 def _gelu_grad(a):
     """d/da of exact GELU: Phi(a) + a * phi(a)."""
     phi = jnp.exp(-0.5 * a * a) * _INV_SQRT_2PI
-    return 0.5 * (1.0 + jax.lax.erf(a / _SQRT2)) + a * phi
+    return 0.5 * (1.0 + _erf(a / _SQRT2)) + a * phi
 
 
 def _ln_fwd(h32, eps):
@@ -340,9 +372,9 @@ def fused_mlp_block(resid, h, ln_scale, ln_bias, w1, b1, w2, b2, gamma,
     leading shape (flattened to rows internally). Parameters are cast
     to the activation dtype first — the same value rounding the unfused
     flax modules apply — and all statistics/epilogues run in fp32.
-    ``interpret=None`` auto-selects interpreter mode off-TPU."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    ``interpret=None``: compiled on the TPU, interpreted on the CPU
+    platform (``ops.resolve_interpret``)."""
+    interpret = resolve_interpret(interpret)
     if resid.shape != h.shape:
         raise ValueError(f"resid/h shape mismatch: {resid.shape} vs "
                          f"{h.shape}")
@@ -385,7 +417,7 @@ def reference_mlp_block(resid, h, ln_scale, ln_bias, w1, b1, w2, b2,
     y = (xn * ls.astype(jnp.float32) + lb.astype(jnp.float32)).astype(cd)
     y = jnp.dot(y, w1, preferred_element_type=jnp.float32) + b1.astype(
         jnp.float32)
-    y = _gelu(y).astype(cd)
+    y = jax.nn.gelu(y, approximate=False).astype(cd)
     y = jnp.dot(y, w2, preferred_element_type=jnp.float32) + b2.astype(
         jnp.float32)
     return (resid.astype(jnp.float32)

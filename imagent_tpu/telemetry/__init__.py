@@ -268,6 +268,13 @@ class TelemetrySession:
             self._max_wait_s = max(self._max_wait_s,
                                    getattr(stats, "max_wait_s", 0.0))
 
+    def absorb_step_wait(self, seconds: float) -> None:
+        """Fold the epoch's summed waits on in-flight step results
+        (``engine._LaggedMetrics.wait_s``) into ``step_drain``; their
+        spans were emitted per wait, like the input waits'."""
+        if self.enabled and self._in_epoch:
+            self.acct.add("step_drain", seconds)
+
     def absorb_eval_input(self, stats) -> None:
         """Fold an EVAL epoch's ``PrefetchStats`` — strictly partitioned
         from the train-side ``absorb_input``: eval wait rides the
@@ -334,9 +341,10 @@ class TelemetrySession:
         if self.health is not None:
             record["health"] = self.health.snapshot()
         if self.chipacct is not None:
-            # Zero-step-cost MFU: achieved flops over the useful
-            # seconds (dispatch + step_drain) the partition above
-            # already measured, against the static account's peak.
+            # Zero-step-cost MFU: achieved flops over the step loop's
+            # seconds (dispatch + step_drain + input_wait) the
+            # partition above already measured, against the static
+            # account's peak.
             # Host floats only — the step loop never pays for this.
             from imagent_tpu.telemetry import chipacct as chipacct_mod
             perf = chipacct_mod.epoch_perf(

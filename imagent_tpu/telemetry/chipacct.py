@@ -22,10 +22,10 @@ generated-code bytes) per device. Combined with:
   re-deriving the mesh math, and free of device syncs;
 
 the account yields zero-step-cost MFU: the goodput wall partition
-already measures useful seconds (``dispatch + step_drain``) and the
-step count, so ``TelemetrySession.epoch_end`` derives
-achieved-flops/s → TFLOP/s-per-chip → MFU from numbers the step loop
-was recording anyway. Nothing here runs inside the step loop, and the
+already measures the step loop's seconds (``dispatch + step_drain +
+input_wait``) and the step count, so ``TelemetrySession.epoch_end``
+derives achieved-flops/s → TFLOP/s-per-chip → MFU from numbers the
+step loop was recording anyway. Nothing here runs inside the step loop, and the
 jaxlint ``blocking-call-in-step-loop`` rule now rejects
 ``cost_analysis()`` / ``memory_analysis()`` / ``memory_stats()``
 calls that ever migrate into one.
@@ -47,6 +47,7 @@ functions, which run exactly once at startup.
 
 from __future__ import annotations
 
+import re
 import time
 from typing import Any
 
@@ -127,6 +128,26 @@ def extract_memory(compiled) -> dict | None:
     return out
 
 
+_COLLECTIVE_OPS = ("all-reduce", "all-gather", "reduce-scatter",
+                   "all-to-all", "collective-permute")
+
+
+def extract_collectives(compiled) -> dict | None:
+    """How many of each collective the compiler put into the
+    executable (sync and ``-start`` async forms alike), from its
+    optimized HLO text — the startup evidence that a multi-chip step
+    really reduces across chips (and that a one-chip step does not).
+    None where the runtime cannot render the text."""
+    try:
+        text = compiled.as_text()
+    except Exception:  # noqa: BLE001 - backend-optional API
+        return None
+    if not text:
+        return None
+    return {op: len(re.findall(rf"= [^=\n]*\b{op}(?:-start)?\(", text))
+            for op in _COLLECTIVE_OPS}
+
+
 def capture_executable(jitted, *args) -> tuple[dict | None, float]:
     """Lower + compile ``jitted`` on ``args`` (concrete arrays and/or
     ShapeDtypeStructs) and extract both analyses. Returns
@@ -145,10 +166,7 @@ def capture_executable(jitted, *args) -> tuple[dict | None, float]:
         compiled = jitted.lower(*args).compile()
     except Exception:  # noqa: BLE001 - accountant is best-effort
         return None, time.perf_counter() - t0
-    facts: dict[str, Any] = dict(extract_cost(compiled) or
-                                 {f: None for f in _EXE_FIELDS})
-    facts["memory"] = extract_memory(compiled)
-    return facts, time.perf_counter() - t0
+    return extract_facts(compiled), time.perf_counter() - t0
 
 
 # ------------------------------------------- state byte attribution
@@ -263,6 +281,7 @@ def extract_facts(compiled) -> dict:
     facts: dict[str, Any] = dict(extract_cost(compiled) or
                                  {f: None for f in _EXE_FIELDS})
     facts["memory"] = extract_memory(compiled)
+    facts["collectives"] = extract_collectives(compiled)
     return facts
 
 
@@ -391,8 +410,13 @@ def plan_line(acct: dict) -> str:
     flops = acct.get("model_flops_per_step")
     flops_part = (f"{flops / 1e9:.2f} GFLOP/step" if flops
                   else "analytic flops unavailable")
+    coll = ((acct.get("train") or {}).get("collectives")) or {}
+    coll_part = ("train-step collectives: " + (", ".join(
+        f"{op} x{n}" for op, n in coll.items() if n) or "none")
+        if coll else "train-step collectives: unreadable")
     return (f"chip accountant: {acct.get('device_kind')} x"
             f"{acct.get('n_devices')}, {flops_part}, {mfu_part}; "
+            f"{coll_part}; "
             f"preflight {acct.get('verdict')}: {byte_table(acct)}")
 
 
@@ -443,14 +467,23 @@ def epoch_perf(acct: dict | None, phases: dict, n_steps: int
     numbers the goodput partition already measured. Pure host floats —
     safe at the epoch boundary, nothing for the step loop.
 
-    useful seconds = dispatch + step_drain (the goodput definition of
-    compile-free step work); achieved = model_flops_per_step x steps /
-    useful; MFU only when the peak is known."""
+    seconds = the compile-free step loop: dispatch + step_drain +
+    input_wait. Dispatch is asynchronous, so the host cannot tell how
+    much of an input wait the chip spent computing the step before —
+    on an input-bound run (first seen on the v5e, PR 21: dispatch +
+    step_drain were 0.25 s of a 4.0 s loop and read as 188% MFU) the
+    two waits only mean something together. What comes out is the
+    end-to-end utilization of the step loop — model flops x steps per
+    second of loop, over chips x peak; input starvation counts against
+    it, eval/checkpoint/compile do not. achieved = model_flops_per_step
+    x steps / seconds; MFU only when the peak is known."""
     if not acct:
         return None
     flops = acct.get("model_flops_per_step")
-    useful = float((phases or {}).get("dispatch", 0.0)
-                   + (phases or {}).get("step_drain", 0.0))
+    phases = phases or {}
+    useful = float(phases.get("dispatch", 0.0)
+                   + phases.get("step_drain", 0.0)
+                   + phases.get("input_wait", 0.0))
     out: dict[str, Any] = {
         "verdict": acct.get("verdict"),
         "modeled_peak_bytes": acct.get("modeled_peak_bytes"),

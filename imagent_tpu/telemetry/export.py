@@ -258,7 +258,7 @@ def render_state(state: dict | None, now: float | None = None) -> str:
         # --no-chipacct run still renders a valid exposition.
         exp.family("imagent_mfu", "gauge",
                    "model FLOPs utilization last epoch (analytic "
-                   "flops over useful seconds, vs chip peak)"
+                   "flops over step-loop seconds, vs chip peak)"
                    ).sample(acct.get("mfu"))
         exp.family("imagent_tflops_per_chip", "gauge",
                    "achieved model TFLOP/s per chip last epoch"

@@ -16,11 +16,12 @@ Phase taxonomy (``PHASES``):
   in between on a steady pipeline.
 * ``dispatch``   — non-compiling step dispatches (host side of useful
   training work; the device computes under them).
-* ``step_drain`` — the epoch-end tail wait (``engine._LaggedMetrics
-  .drain``): the host waiting for the device to retire the last
-  ``_GUARD_LAG`` dispatched steps — the device-side tail of useful
-  training work (the rest of the epoch's vectors were consumed lagged,
-  behind the dispatch, at zero wait).
+* ``step_drain`` — the host waiting for the device to retire
+  dispatched steps (``engine._LaggedMetrics.wait_s``): every read of
+  the lagged metric frontier plus the epoch-end tail drain. Dispatch
+  is asynchronous, so on a device-bound run THIS is where the epoch
+  goes — the device side of useful training work; where the host is
+  the bottleneck the reads find their vectors ready and it is ~0.
 * ``input_wait`` — step loop blocked on the staging queue
   (``data/prefetch.py::PrefetchStats.wait_s``).
 * ``eval``       — validation epochs.
@@ -41,7 +42,12 @@ them under ``overlap``.
 
 ``goodput`` = (compile-free step work) / wall =
 ``(dispatch + step_drain) / wall`` — the fraction of the epoch that
-bought optimizer progress.
+bought optimizer progress. A host-side LOWER bound: while the step
+loop sits in ``input_wait`` the chip may still be computing the step
+dispatched before, and the host cannot see it — on an input-bound run
+(the v5e chip smoke with host-generated synthetic data) goodput reads
+near 0 while the chip is busy for most of each input wait. Device busy
+and idle shares come from a profiler trace, not from this partition.
 
 This module is imported per training step (via ``TelemetrySession``)
 and therefore must stay jax-free: pure host arithmetic on floats, no
@@ -62,11 +68,11 @@ OVERLAP_PHASES = ("ckpt_commit_async",)
 # A step dispatch is asynchronous (microseconds); one that blocks this
 # long was compiling/retracing.  Conservative: a genuinely slow host
 # misattributing one dispatch to `compile` costs nothing downstream.
-# Known caveat: the CPU backend sometimes executes small programs
-# synchronously inside dispatch, so CPU smoke runs over-attribute
-# steady steps to `compile` — on TPU (the platform this accounts for)
-# the µs-vs-seconds gap is unambiguous, and either way the phases
-# still sum to the measured wall.
+# Known caveat: the CPU backend executes programs synchronously
+# inside dispatch, so a CPU run of a large model attributes steady
+# steps to `compile` — on the TPU (the platform this accounts for,
+# checked by chip_smoke.py) dispatch returns in well under the
+# threshold, and either way the phases still sum to the measured wall.
 COMPILE_THRESHOLD_S = 0.5
 
 

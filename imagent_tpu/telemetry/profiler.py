@@ -113,19 +113,29 @@ class ProfilerSession:
 
 def hbm_stats() -> dict | None:
     """Per-device memory stats from the PJRT runtime, or None where
-    unimplemented (CPU).  Reports the first local device (the engine's
-    process-local view; HBM is symmetric across a pod's chips)."""
+    unimplemented (CPU).  The headline keys report the first local
+    device (the engine's process-local view; HBM is symmetric across a
+    pod's chips); ``devices`` lists bytes in use / peak for EVERY local
+    device, so a mesh whose work is not really spread shows up as a
+    chip holding nothing."""
     import jax
-    try:
-        stats = jax.local_devices()[0].memory_stats()
-    except Exception:
-        return None
-    if not stats:
-        return None
     keep = ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")
-    out = {k: int(stats[k]) for k in keep if k in stats}
+    per_device = []
+    for d in jax.local_devices():
+        try:
+            stats = d.memory_stats()
+        except Exception:
+            return None
+        if not stats:
+            return None
+        per_device.append({"id": int(d.id),
+                           **{k: int(stats[k]) for k in keep
+                              if k in stats}})
+    out = {k: v for k, v in per_device[0].items() if k != "id"}
     if not out:
         return None
+    out["devices"] = [{k: v for k, v in d.items() if k != "bytes_limit"}
+                      for d in per_device]
     if out.get("bytes_limit"):
         # Peak-fraction gauge: the headroom number an operator tunes
         # batch size / remat / fused kernels against, without opening

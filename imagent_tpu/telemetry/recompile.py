@@ -164,7 +164,12 @@ class _CompileNameHandler(logging.Handler):
         if isinstance(msg, str) and msg.startswith("Compiling") \
                 and record.args:
             try:
-                sentinel.note_fun_name(str(record.args[0]))
+                # The installed jax logs the wrapped name,
+                # "jit(train_step)": report the function's own.
+                name = str(record.args[0])
+                if name.startswith("jit(") and name.endswith(")"):
+                    name = name[4:-1]
+                sentinel.note_fun_name(name)
             except Exception:  # noqa: BLE001 — a log hook must not
                 pass           # take down the compile it observes
 

@@ -44,7 +44,6 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from imagent_tpu.cluster import DATA_AXIS, MODEL_AXIS, PIPE_AXIS
-from imagent_tpu.compat.jaxcompat import shard_map
 from imagent_tpu.ops import softmax_cross_entropy
 from imagent_tpu.parallel import pmean_tree
 from imagent_tpu.utils.metrics import topk_correct
@@ -61,10 +60,9 @@ class TrainState(flax.struct.PyTreeNode):
     ModelEmaV2 semantics, which decays all buffers): the live running
     stats track the LIVE params' activation distribution, so evaluating
     EMA params against them diverges whenever the params move fast
-    relative to the EMA horizon — observed catastrophically on the
-    round-4 draft run (val loss 3817 mid-run at decay 0.999,
-    docs/runs/imagenet_shaped_r4draft_tpu.log) before this field
-    existed."""
+    relative to the EMA horizon — observed catastrophically on a
+    round-4 draft run (val loss 3817 mid-run at decay 0.999) before
+    this field existed."""
 
     step: jnp.ndarray
     params: Any
@@ -688,7 +686,7 @@ def make_train_step(model, optimizer: optax.GradientTransformation,
         return new_state, metrics
 
     st = state_specs if state_specs is not None else P()
-    sharded = shard_map(
+    sharded = jax.shard_map(
         per_device_step, mesh=mesh,
         in_specs=(st, P(DATA_AXIS), P(DATA_AXIS), P()),
         out_specs=(st, P()),
@@ -885,7 +883,7 @@ def make_eval_step(model, mesh: Mesh,
                         DATA_AXIS)
 
     st = state_specs if state_specs is not None else P()
-    sharded = shard_map(
+    sharded = jax.shard_map(
         per_device_eval, mesh=mesh,
         in_specs=(st, P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS)),
         out_specs=P(),

@@ -112,10 +112,14 @@ def _acct(**kw):
 
 
 def test_epoch_perf_mfu_math():
-    # 100 steps of 1 TFLOP over 10 useful seconds on 4 chips:
-    # 10 TFLOP/s achieved -> 2.5 TFLOP/s/chip -> mfu 2.5/275.
+    # 100 steps of 1 TFLOP over 10 step-loop seconds on 4 chips:
+    # 10 TFLOP/s achieved -> 2.5 TFLOP/s/chip -> mfu 2.5/275. The
+    # loop's seconds are dispatch + step_drain + input_wait (an
+    # input-bound loop's device work hides under the input wait);
+    # eval/checkpoint/compile seconds are not the step loop's.
     perf = chipacct.epoch_perf(
-        _acct(), {"dispatch": 8.0, "step_drain": 2.0}, 100)
+        _acct(), {"dispatch": 1.0, "step_drain": 2.0, "input_wait": 7.0,
+                  "eval": 5.0, "checkpoint": 3.0, "compile": 9.0}, 100)
     assert perf["tflops_per_chip"] == pytest.approx(2.5)
     assert perf["mfu"] == pytest.approx(2.5 / 275.0, abs=1e-4)
     assert perf["verdict"] == "ok"
@@ -173,7 +177,7 @@ def test_plan_line_carries_preflight_verdict():
 
 def _cfg(root, **kw):
     from imagent_tpu.config import Config
-    base = dict(arch="resnet18", image_size=16, num_classes=4,
+    base = dict(backend="cpu", arch="resnet18", image_size=16, num_classes=4,
                 batch_size=4, epochs=2, lr=0.05, dataset="synthetic",
                 synthetic_size=64, workers=0, bf16=False, log_every=0,
                 seed=0, save_model=False, eval_every=2,

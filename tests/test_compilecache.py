@@ -1,5 +1,6 @@
 """Warm starts (PR 20): the persistent AOT executable store, the
-one-compile startup, and the wash contract for loaded executables.
+one-compile startup, where the cache lives (one resolver), and the
+loaded-donated-executable exactness case.
 
 Layers under test, cheapest first: the jax-free fingerprint/store
 pieces (pure pickle + JSON), the completeness guard that diffs the
@@ -44,7 +45,7 @@ def _fp(cfg, **over):
 
 
 def test_fingerprint_deterministic_and_sensitive():
-    cfg = Config(arch="resnet18", image_size=16, num_classes=4)
+    cfg = Config(backend="cpu", arch="resnet18", image_size=16, num_classes=4)
     k0 = compilecache.cache_key(_fp(cfg))
     assert re.fullmatch(r"[0-9a-f]{16}", k0)
     assert compilecache.cache_key(_fp(cfg)) == k0  # deterministic
@@ -52,7 +53,7 @@ def test_fingerprint_deterministic_and_sensitive():
     # step builders consume, the topology, the batch geometry, the
     # gradient-accumulation factor, and the runtime versions.
     assert compilecache.cache_key(
-        _fp(Config(arch="resnet18", image_size=16, num_classes=4,
+        _fp(Config(backend="cpu", arch="resnet18", image_size=16, num_classes=4,
                    label_smoothing=0.123))) != k0
     assert compilecache.cache_key(
         _fp(cfg, mesh_shape={"data": 4, "pipe": 1, "model": 2})) != k0
@@ -65,7 +66,7 @@ def test_fingerprint_deterministic_and_sensitive():
 def test_fingerprint_is_pure_data():
     """The fingerprint must round-trip canonical JSON — no tuples, no
     numpy scalars, nothing the store's preimage file would mangle."""
-    fp = _fp(Config(arch="vit_s16", image_size=32, num_classes=10))
+    fp = _fp(Config(backend="cpu", arch="vit_s16", image_size=32, num_classes=10))
     blob = json.dumps(fp, sort_keys=True)
     assert json.loads(blob) == fp
 
@@ -102,7 +103,7 @@ def test_cache_key_completeness_guard():
 
 def test_store_roundtrip_and_corruption(tmp_path):
     store = compilecache.ExecutableStore(str(tmp_path / "aot"))
-    fp = _fp(Config(arch="resnet18", image_size=16, num_classes=4))
+    fp = _fp(Config(backend="cpu", arch="resnet18", image_size=16, num_classes=4))
     key = compilecache.cache_key(fp)
     triple = (b"payload-bytes", {"in": 1}, {"out": 2})
     assert store.load(key, "train", 0, 1) is None  # empty = miss
@@ -127,7 +128,7 @@ def test_store_roundtrip_and_corruption(tmp_path):
 
 def test_store_entries_and_prune(tmp_path):
     store = compilecache.ExecutableStore(str(tmp_path / "aot"))
-    cfg = Config(arch="resnet18", image_size=16, num_classes=4)
+    cfg = Config(backend="cpu", arch="resnet18", image_size=16, num_classes=4)
     fps = [_fp(cfg), _fp(cfg, global_batch=64)]
     keys = [compilecache.cache_key(f) for f in fps]
     for f, k in zip(fps, keys):
@@ -142,38 +143,111 @@ def test_store_entries_and_prune(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# Probe verdict caching
+# Where the cache lives: one resolver (jax-free half)
 # ---------------------------------------------------------------------------
 
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-def test_probe_verdict_cached(tmp_path, monkeypatch):
-    """The verdict is keyed on the runtime token: a cached entry is
-    honored without respawning children, and a token change (runtime
-    upgrade, probe version bump) re-probes."""
-    cache = tmp_path / "cc"
-    cache.mkdir()
-    token = compilecache.probe_token()
-    (cache / compilecache.PROBE_FILENAME).write_text(json.dumps(
-        {"token": token, "ok": False, "detail": "synthetic verdict"}))
-    calls = {"n": 0}
 
-    def no_spawn(*a, **k):
-        calls["n"] += 1
-        raise AssertionError("probe must not spawn on a cached verdict")
+def test_resolver_env_wins_else_fixed_in_checkout_path(tmp_path,
+                                                       monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set -> that directory; unset (or
+    blank) -> the fixed <checkout>/.jax_cache, which .gitignore lists.
+    Never a temporary, pid- or time-derived path."""
+    monkeypatch.setenv(compilecache.CACHE_DIR_ENV, str(tmp_path / "x"))
+    assert compilecache.resolve_cache_dir() == str(tmp_path / "x")
+    monkeypatch.setenv(compilecache.CACHE_DIR_ENV, "  ")
+    assert compilecache.resolve_cache_dir() == \
+        compilecache.DEFAULT_CACHE_DIR
+    monkeypatch.delenv(compilecache.CACHE_DIR_ENV)
+    assert compilecache.resolve_cache_dir() == \
+        compilecache.DEFAULT_CACHE_DIR
+    assert compilecache.DEFAULT_CACHE_DIR == os.path.join(
+        _REPO, ".jax_cache")
+    with open(os.path.join(_REPO, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
 
-    monkeypatch.setattr(compilecache.subprocess, "run", no_spawn)
-    ok, detail = compilecache.probe(str(cache))
-    assert (ok, detail) == (False, "synthetic verdict")
-    assert calls["n"] == 0
-    # Stale token → must re-probe (the monkeypatched spawn trips).
-    (cache / compilecache.PROBE_FILENAME).write_text(json.dumps(
-        {"token": dict(token, probe=-1), "ok": True, "detail": "old"}))
-    with pytest.raises(AssertionError):
-        compilecache.probe(str(cache))
+
+def test_one_cache_dir_update_in_the_tree():
+    """No code but the resolver points JAX's cache anywhere: grep over
+    every python file git would commit (this one, which spells the
+    pattern, aside)."""
+    files = subprocess.run(
+        ["git", "ls-files", "-co", "--exclude-standard", "*.py"],
+        cwd=_REPO, capture_output=True, text=True,
+        check=True).stdout.split()
+    setter = re.compile(
+        r"""update\(\s*["']jax_compilation_cache_dir"""
+        r"""|set_cache_dir\(|initialize_cache\(""")
+    hits = []
+    for rel in files:
+        if rel == "tests/test_compilecache.py":
+            continue
+        with open(os.path.join(_REPO, rel)) as f:
+            hits += [f"{rel}:{n}" for n, line in enumerate(f, 1)
+                     if setter.search(line)]
+    assert [h.split(":")[0] for h in hits] == \
+        ["imagent_tpu/compilecache.py"], hits
+
+
+def test_arm_unset_env_uses_the_fixed_default(tmp_path, monkeypatch,
+                                              compile_cache_dir):
+    """Unset, the engine's ``arm`` points JAX at the fixed default
+    (patched to a tmp dir here so the test leaves the checkout
+    alone); with JAX's own switch off it arms nothing."""
+    import jax
+
+    monkeypatch.delenv(compilecache.CACHE_DIR_ENV)
+    fixed = str(tmp_path / "fixed_default")
+    monkeypatch.setattr(compilecache, "DEFAULT_CACHE_DIR", fixed)
+    assert compilecache.arm() == fixed
+    assert jax.config.jax_compilation_cache_dir == fixed
+    assert os.path.isdir(fixed)
+    jax.config.update("jax_enable_compilation_cache", False)
+    assert compilecache.arm() is None
+
+
+def test_warm_cli_writes_under_env_dir_only(tmp_path):
+    """``compilecache warm`` takes no directory of its own: it fills
+    the cache the environment names — and the engine run it warmed
+    then starts with 2 hits from there."""
+    cache = tmp_path / "placed"
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(cache),
+               JAX_ENABLE_COMPILATION_CACHE="true",
+               XLA_FLAGS="--xla_force_host_platform_device_count=8")
+    flags = ["--backend", "cpu", "--arch", "resnet18", "--image-size",
+             "16", "--num-classes", "4", "--batch-size", "4",
+             "--no-bf16", "--dataset", "synthetic",
+             "--synthetic-size", "64", "--workers", "0",
+             "--log-every", "0", "--epochs", "1",
+             "--log-dir", str(tmp_path / "tb"),
+             "--ckpt-dir", str(tmp_path / "ck")]
+    default = compilecache.DEFAULT_CACHE_DIR
+    before = os.path.getmtime(default) if os.path.isdir(default) \
+        else None
+    warm = subprocess.run(
+        [sys.executable, "-m", "imagent_tpu.compilecache", "warm",
+         "--", *flags], capture_output=True, text=True, timeout=600,
+        env=env, cwd=_REPO)
+    assert warm.returncode == 0, (warm.stdout + warm.stderr)[-2000:]
+    assert re.search(r"0 hit\(s\), 2 compiled, 2 saved", warm.stdout)
+    assert str(cache) in warm.stdout  # the plan line names the place
+    entries = [d for d in (cache / "aot").iterdir() if d.is_dir()]
+    assert len(entries) == 1
+    run = subprocess.run(
+        [sys.executable, "-m", "imagent_tpu", *flags],
+        capture_output=True, text=True, timeout=600, env=env,
+        cwd=_REPO)
+    assert run.returncode == 0, (run.stdout + run.stderr)[-2000:]
+    assert re.search(r"2 hit\(s\), 0 compiled, 0 saved", run.stdout)
+    after = os.path.getmtime(default) if os.path.isdir(default) \
+        else None
+    assert before == after  # the in-checkout default was not touched
 
 
 # ---------------------------------------------------------------------------
-# Dispatch wrapper + wash (jax, in-process)
+# Dispatch wrapper + the loaded-donated-executable case (in-process)
 # ---------------------------------------------------------------------------
 
 
@@ -205,26 +279,40 @@ def test_compiled_step_fallback_on_geometry_change(mesh8):
     assert stats["fallback_steps"] == 2
 
 
-def test_wash_state_produces_fresh_executable_buffers(mesh8):
-    """wash_state's contract (the jax<0.5 loaded-donated-executable
-    defect): same values, same shardings, same tree — but every leaf
-    backed by a NEW buffer that came out of an XLA computation, bool
-    and integer leaves included."""
+def test_loaded_donated_executable_exact_on_host_buffers():
+    """The repo's isolating case for the old-runtime defect that the
+    removed ``wash_state`` fenced: a DESERIALIZED executable with a
+    donated argument fed host-committed ``device_put`` buffers
+    (exactly what checkpoint restore and torch import produce)
+    miscomputed 12/12 on jax < 0.5. On the installed jax it computes
+    exactly, first call and chained, at small and large sizes — which
+    is why restored states now go straight to the loaded executables
+    (also run on the TPU, CHANGES.md PR 21)."""
     import jax
+    import jax.numpy as jnp
+    from jax.experimental import serialize_executable as serexe
 
-    state = {
-        "w": jax.device_put(np.arange(8.0, dtype=np.float32)),
-        "step": jax.device_put(np.int32(7)),
-        "flag": jax.device_put(np.bool_(True)),
-    }
-    washed = compilecache.wash_state(state)
-    assert jax.tree.structure(washed) == jax.tree.structure(state)
-    for a, b in zip(jax.tree.leaves(state), jax.tree.leaves(washed)):
-        assert a.dtype == b.dtype and a.shape == b.shape
-        assert np.array_equal(np.asarray(a), np.asarray(b))
-        pa = a.addressable_shards[0].data.unsafe_buffer_pointer()
-        pb = b.addressable_shards[0].data.unsafe_buffer_pointer()
-        assert pa != pb, "wash must copy, not forward, the buffer"
+    def step(s, x):
+        return s + x, (s * x).sum()
+
+    ref = jax.jit(step)
+    for n in (8, 1 << 12, 1 << 18):
+        x = jnp.ones(n, jnp.float32)
+        compiled = jax.jit(step, donate_argnums=0).lower(x, x).compile()
+        loaded = serexe.deserialize_and_load(
+            *serexe.serialize(compiled),
+            execution_devices=jax.devices()[:1])
+        del compiled
+        for _ in range(4):
+            host = np.arange(n, dtype=np.float32) % 97
+            want_s, want_v = ref(jnp.asarray(host), x)
+            want_s2, want_v2 = ref(want_s, x)
+            got_s, got_v = loaded(jax.device_put(host), x)  # donated
+            got_s2, got_v2 = loaded(got_s, x)               # chained
+            assert float(got_v) == float(want_v)
+            assert float(got_v2) == float(want_v2)
+            assert np.array_equal(np.asarray(got_s2),
+                                  np.asarray(want_s2))
 
 
 # ---------------------------------------------------------------------------
@@ -306,7 +394,7 @@ def test_chipacct_reuses_aot_executables(tmp_path, monkeypatch):
         return acct
 
     monkeypatch.setattr(chipacct, "build_account", capture_build)
-    result = run(Config(
+    result = run(Config(backend="cpu",
         arch="resnet18", image_size=16, num_classes=4, batch_size=4,
         epochs=1, lr=0.05, dataset="synthetic", synthetic_size=64,
         workers=0, bf16=False, log_every=0, seed=0,
@@ -326,21 +414,25 @@ from imagent_tpu.config import Config
 from imagent_tpu.engine import run
 
 tmp, phase = sys.argv[1], sys.argv[2]
-cfg = Config(
+cfg = Config(backend="cpu",
     arch="resnet18", image_size=16, num_classes=4, batch_size=4,
     epochs=(1 if phase == "cold" else 2), lr=0.05,
     dataset="synthetic", synthetic_size=128, workers=0, bf16=False,
     log_every=0, seed=0, save_model=True, resume=(phase == "warm"),
-    log_dir=os.path.join(tmp, "tb"), ckpt_dir=os.path.join(tmp, "ckpt"),
-    compile_cache=os.path.join(tmp, "xla_cache"))
+    log_dir=os.path.join(tmp, "tb"), ckpt_dir=os.path.join(tmp, "ckpt"))
 result = run(cfg)
 assert result["best_epoch"] >= 0
+print("FINAL_TRAIN_LOSS", repr(float(result["final_train"]["loss"])))
 """
 
 
-def _spawn_engine(tmp, phase):
+def _spawn_engine(tmp, phase, cache=True):
+    # The cache is placed from outside, the one way there is: the
+    # environment variable, under the run's own tmp root.
     env = dict(os.environ,
                JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=os.path.join(str(tmp), "cc"),
+               JAX_ENABLE_COMPILATION_CACHE="true" if cache else "false",
                XLA_FLAGS="--xla_force_host_platform_device_count=8")
     proc = subprocess.run(
         [sys.executable, "-c", _DRILL_CHILD, str(tmp), phase],
@@ -369,11 +461,29 @@ def test_warm_start_drill(tmp_path):
     (2 hits, 0 compiles), its compile/startup phase lands well under
     30% of the cold wall, the hit counters surface in telemetry.jsonl
     and status.json, and no dispatch falls back to the jitted twin."""
+    import shutil
+
     cold_out = _spawn_engine(tmp_path, "cold")
     assert re.search(r"compile cache: key [0-9a-f]{16} — 0 hit\(s\), "
                      r"2 compiled, 2 saved", cold_out)
+    # The reference for the exactness check below: the same resume
+    # from a copy of the same checkpoint, persistent cache OFF (cold
+    # compiled executables, nothing deserialized).
+    ref_root = tmp_path / "ref"
+    shutil.copytree(tmp_path / "ckpt", ref_root / "ckpt")
     warm_out = _spawn_engine(tmp_path, "warm")
     assert re.search(r"2 hit\(s\), 0 compiled, 0 saved", warm_out)
+    ref_out = _spawn_engine(ref_root, "warm", cache=False)
+    assert "persistent cache OFF" in ref_out
+
+    def final_loss(out):
+        return float(re.search(r"FINAL_TRAIN_LOSS (\S+)", out).group(1))
+
+    # No wash any more: the restored (host-committed, device_put)
+    # state goes straight into the LOADED donated executables — and
+    # must train exactly like the cold-compiled reference.
+    assert final_loss(warm_out) == pytest.approx(final_loss(ref_out),
+                                                 rel=1e-6)
 
     stamps = _startup_stats(tmp_path)
     assert len(stamps) == 2
@@ -384,9 +494,7 @@ def test_warm_start_drill(tmp_path):
     assert warm["startup_s"] < 0.30 * cold["startup_s"], (
         f"warm startup {warm['startup_s']}s not under 30% of cold "
         f"{cold['startup_s']}s")
-    # The restored state was washed before reaching the loaded
-    # executables (the jax<0.5 donation defect fence).
-    assert warm.get("washes", 0) >= 1
+    assert "washes" not in warm  # the wash and its counter are gone
     # status.json carries the same stamp for jax-free dashboards.
     import glob
 
@@ -396,7 +504,7 @@ def test_warm_start_drill(tmp_path):
     st = json.loads(open(sj[0]).read())
     assert (st.get("compile_cache") or {}).get("hits") == 2
     # Store on disk: one fingerprint entry, per-step executables.
-    aot = tmp_path / "xla_cache" / "aot"
+    aot = tmp_path / "cc" / "aot"
     entries = [d for d in aot.iterdir() if d.is_dir()]
     assert len(entries) == 1
     assert (entries[0] / "fingerprint.json").is_file()

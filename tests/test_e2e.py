@@ -2,15 +2,17 @@
 init→shard→step→psum→metrics→log→checkpoint path on 8 fake devices with
 synthetic data — the BASELINE.json "CPU smoke" config, hardware-free."""
 
+import os
+
 from imagent_tpu.config import Config
 from imagent_tpu.engine import run
 
 
 def _tiny_cfg(tmp_path, **kw):
     base = dict(
-        arch="resnet18", image_size=16, num_classes=4, batch_size=4,
-        epochs=2, lr=0.05, dataset="synthetic", synthetic_size=128,
-        workers=0, bf16=False, log_every=0, seed=0,
+        backend="cpu", arch="resnet18", image_size=16, num_classes=4,
+        batch_size=4, epochs=2, lr=0.05, dataset="synthetic",
+        synthetic_size=128, workers=0, bf16=False, log_every=0, seed=0,
         log_dir=str(tmp_path / "tb"), ckpt_dir=str(tmp_path / "ckpt"))
     base.update(kw)
     return Config(**base)
@@ -81,9 +83,7 @@ def test_e2e_eval_only(tmp_path):
 def test_e2e_async_ckpt_durability(tmp_path):
     """The async snapshot-then-commit LAST path (the default): commits
     land durably off the critical path — meta + manifest written, the
-    in-progress marker cleared — and --resume restores them. Split
-    from the compile-cache test so this path runs on the CI jax
-    instead of riding the jax<0.5 persistent-cache skip."""
+    in-progress marker cleared — and --resume restores them."""
     cfg = _tiny_cfg(tmp_path, epochs=2, save_model=True)
     assert cfg.async_ckpt  # the default; the sync baseline is the flag
     run(cfg)
@@ -102,27 +102,34 @@ def test_e2e_async_ckpt_durability(tmp_path):
     assert result["best_epoch"] >= 0
 
 
-def test_e2e_compile_cache(tmp_path):
-    """--compile-cache populates the persistent XLA cache AND the
-    serialized AOT executable store, and a resumed run reuses both.
-    Un-skipped in PR 20: the capability probe (compilecache.probe)
-    now fences the historical jax<0.5 reload segfault in a throwaway
-    subprocess at engine startup, so this path is safe wherever it
-    runs — on a runtime that would crash, the engine downgrades to
-    cold compiles instead of entering this code path at all."""
-    cache = tmp_path / "xla_cache"
-    cfg = _tiny_cfg(tmp_path, epochs=2, save_model=True,
-                    compile_cache=str(cache))
+def test_e2e_compile_cache(tmp_path, compile_cache_dir):
+    """With the cache placed from outside (JAX_COMPILATION_CACHE_DIR —
+    the ``compile_cache_dir`` fixture) the engine populates the
+    persistent XLA cache AND the serialized AOT executable store there
+    and nowhere else, in the process that holds the device (no probe
+    children), and a resumed run in the same process steps the
+    restored state through the LOADED donated executables."""
+    from imagent_tpu import compilecache
+
+    cache = compile_cache_dir
+    before = (os.path.getmtime(compilecache.DEFAULT_CACHE_DIR)
+              if os.path.isdir(compilecache.DEFAULT_CACHE_DIR) else None)
+    cfg = _tiny_cfg(tmp_path, epochs=2, save_model=True)
     run(cfg)
     assert cache.is_dir() and any(cache.iterdir())  # cache written
-    # Probe verdict cached; AOT store populated (one entry dir with
-    # the fingerprint preimage + train/eval executables).
-    assert (cache / "probe.json").is_file()
+    # No probe verdict/scratch any more; AOT store populated (one
+    # entry dir with the fingerprint preimage + train/eval
+    # executables).
+    assert not (cache / "probe.json").exists()
+    assert not (cache / ".probe_scratch").exists()
     aot_entries = [d for d in (cache / "aot").iterdir() if d.is_dir()]
     assert len(aot_entries) == 1
     assert (aot_entries[0] / "fingerprint.json").is_file()
     assert any(f.suffix == ".exe" for f in aot_entries[0].iterdir())
-    cfg2 = _tiny_cfg(tmp_path, epochs=3, save_model=True, resume=True,
-                     compile_cache=str(cache))
+    # ...and the in-checkout default was not touched.
+    after = (os.path.getmtime(compilecache.DEFAULT_CACHE_DIR)
+             if os.path.isdir(compilecache.DEFAULT_CACHE_DIR) else None)
+    assert before == after
+    cfg2 = _tiny_cfg(tmp_path, epochs=3, save_model=True, resume=True)
     result = run(cfg2)
     assert result["best_epoch"] >= 0

@@ -69,7 +69,7 @@ def test_engine_ema_trains_and_resumes(tmp_path):
     from imagent_tpu.config import Config
     from imagent_tpu.engine import run
 
-    cfg = Config(arch="resnet18", image_size=16, num_classes=4,
+    cfg = Config(backend="cpu", arch="resnet18", image_size=16, num_classes=4,
                  batch_size=4, epochs=2, lr=0.05, dataset="synthetic",
                  synthetic_size=32, workers=0, bf16=False, log_every=0,
                  ema_decay=0.9, save_model=True,
@@ -89,7 +89,7 @@ def test_eval_uses_ema_weights(tmp_path):
     from imagent_tpu.config import Config
     from imagent_tpu.engine import run
 
-    base = dict(arch="resnet18", image_size=16, num_classes=4,
+    base = dict(backend="cpu", arch="resnet18", image_size=16, num_classes=4,
                 batch_size=8, epochs=2, lr=0.2, dataset="synthetic",
                 synthetic_size=64, workers=0, bf16=False, log_every=0,
                 log_dir=str(tmp_path / "tb1"),
@@ -152,7 +152,7 @@ def test_engine_enables_ema_mid_run(tmp_path):
     from imagent_tpu.config import Config
     from imagent_tpu.engine import run
 
-    base = dict(arch="resnet18", image_size=16, num_classes=4,
+    base = dict(backend="cpu", arch="resnet18", image_size=16, num_classes=4,
                 batch_size=4, epochs=1, lr=0.05, dataset="synthetic",
                 synthetic_size=32, workers=0, bf16=False, log_every=0,
                 save_model=True, log_dir=str(tmp_path / "tb"),

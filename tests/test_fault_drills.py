@@ -29,7 +29,7 @@ def _disarm_faults():
 
 
 def _cfg(tmp_path, **kw):
-    base = dict(arch="resnet18", image_size=16, num_classes=4, batch_size=4,
+    base = dict(backend="cpu", arch="resnet18", image_size=16, num_classes=4, batch_size=4,
                 epochs=2, lr=0.05, dataset="synthetic", synthetic_size=128,
                 workers=0, bf16=False, log_every=0, seed=0, save_model=True,
                 log_dir=str(tmp_path / "tb"), ckpt_dir=str(tmp_path / "ck"))

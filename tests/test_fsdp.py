@@ -112,7 +112,7 @@ def test_fsdp_e2e_smoke(tmp_path):
     from imagent_tpu.config import Config
     from imagent_tpu.engine import run
 
-    cfg = Config(arch="resnet18", image_size=16, num_classes=4, batch_size=4,
+    cfg = Config(backend="cpu", arch="resnet18", image_size=16, num_classes=4, batch_size=4,
                  epochs=2, lr=0.05, dataset="synthetic", synthetic_size=64,
                  workers=0, bf16=False, log_every=0, fsdp=True,
                  save_model=True, log_dir=str(tmp_path / "tb"),
@@ -178,7 +178,7 @@ def test_fsdp_grad_accum_e2e_smoke(tmp_path):
     from imagent_tpu.config import Config
     from imagent_tpu.engine import run
 
-    cfg = Config(arch="resnet18", image_size=16, num_classes=4, batch_size=2,
+    cfg = Config(backend="cpu", arch="resnet18", image_size=16, num_classes=4, batch_size=2,
                  grad_accum=2, epochs=1, lr=0.05, dataset="synthetic",
                  synthetic_size=64, workers=0, bf16=False, log_every=0,
                  fsdp=True, optimizer="adamw", save_model=True,

@@ -31,7 +31,7 @@ def _kernel_args(rng, dtype):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_kernel_forward_parity(dtype):
     args = _kernel_args(np.random.default_rng(0), dtype)
-    got = fused_mlp_block(*args, block_rows=16)
+    got = fused_mlp_block(*args, block_rows=16, interpret=True)
     want = reference_mlp_block(*args)
     assert got.dtype == want.dtype == dtype
     tol = 1e-5 if dtype == jnp.float32 else 3e-2
@@ -48,7 +48,8 @@ def test_kernel_backward_parity(dtype):
 
     def loss_fused(a):
         return jnp.sum(jnp.square(
-            fused_mlp_block(*a, block_rows=16).astype(jnp.float32)))
+            fused_mlp_block(*a, block_rows=16,
+                            interpret=True).astype(jnp.float32)))
 
     def loss_ref(a):
         return jnp.sum(jnp.square(
@@ -333,3 +334,45 @@ def test_ddp_equivalence_fused_train_step():
         np.testing.assert_allclose(
             np.asarray(e), np.asarray(g), rtol=1e-4, atol=1e-6,
             err_msg=jax.tree_util.keystr(pa))
+
+
+def test_kernel_erf_polynomial_matches_lax_erf():
+    """The in-kernel erf (erf has no Pallas TPU lowering; the kernel
+    evaluates XLA's own f32 rational polynomial from mul/add/div/clamp)
+    against ``jax.lax.erf``: within a few ulp of the result everywhere
+    (both saturate to +-1 within one ulp), and exact-GELU value +
+    derivative built on it
+    match ``nn.gelu(approximate=False)`` and its autodiff."""
+    from imagent_tpu.ops.fused_mlp import _erf, _gelu, _gelu_grad
+
+    x = jnp.asarray(np.concatenate([
+        np.linspace(-8.0, 8.0, 400_001),
+        np.random.default_rng(0).normal(size=100_000) * 2.0,
+    ]).astype(np.float32))
+    assert float(jnp.max(jnp.abs(_erf(x) - jax.lax.erf(x)))) <= 6e-7
+    assert abs(float(_erf(jnp.float32(9.0))) - 1.0) <= 2e-7
+    assert abs(float(_erf(jnp.float32(-9.0))) + 1.0) <= 2e-7
+    np.testing.assert_allclose(
+        np.asarray(_gelu(x)), np.asarray(nn.gelu(x, approximate=False)),
+        rtol=0, atol=2e-6)
+    want = jax.vmap(jax.grad(lambda a: nn.gelu(a, approximate=False)))(x)
+    np.testing.assert_allclose(np.asarray(_gelu_grad(x)),
+                               np.asarray(want), rtol=0, atol=2e-6)
+
+
+def test_interpret_is_explicit_or_follows_the_platform(monkeypatch):
+    """``interpret=None`` compiles on the TPU and interprets only on
+    the CPU platform (which tests and rehearsals select explicitly);
+    any other platform is refused, never quietly interpreted. An
+    explicit bool always wins."""
+    from imagent_tpu.ops import resolve_interpret
+
+    assert resolve_interpret(True) is True
+    assert resolve_interpret(False) is False
+    assert resolve_interpret(None) is True  # this suite runs on the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_interpret(None) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu' platform"):
+        resolve_interpret(None)
+    assert resolve_interpret(True) is True

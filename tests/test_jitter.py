@@ -77,7 +77,7 @@ def test_engine_jitter_smoke(tmp_path):
     from imagent_tpu.config import Config
     from imagent_tpu.engine import run
 
-    cfg = Config(arch="resnet18", image_size=16, num_classes=4,
+    cfg = Config(backend="cpu", arch="resnet18", image_size=16, num_classes=4,
                  batch_size=4, epochs=1, lr=0.05, dataset="synthetic",
                  synthetic_size=32, workers=0, bf16=False, log_every=0,
                  color_jitter=(0.4, 0.4, 0.2), mixup=0.2,
@@ -97,7 +97,7 @@ def test_full_extended_recipe_composes(tmp_path):
     from imagent_tpu.engine import run
 
     # global batch = 2 x 8 devices x 2 accum = 32 = the dataset
-    cfg = Config(arch="resnet18", image_size=16, num_classes=4,
+    cfg = Config(backend="cpu", arch="resnet18", image_size=16, num_classes=4,
                  batch_size=2, epochs=2, lr=0.05, dataset="synthetic",
                  synthetic_size=32, workers=0, bf16=False, log_every=0,
                  color_jitter=(0.4, 0.4, 0.4), mixup=0.2, cutmix=1.0,

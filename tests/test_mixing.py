@@ -139,7 +139,7 @@ def test_engine_accepts_mixing_flags(tmp_path):
     from imagent_tpu.config import Config
     from imagent_tpu.engine import run
 
-    cfg = Config(arch="resnet18", image_size=16, num_classes=4,
+    cfg = Config(backend="cpu", arch="resnet18", image_size=16, num_classes=4,
                  batch_size=4, epochs=1, lr=0.05, dataset="synthetic",
                  synthetic_size=32, workers=0, bf16=False, log_every=0,
                  mixup=0.2, cutmix=1.0,

@@ -272,7 +272,7 @@ def test_convnext_engine_smoke(tmp_path):
     from imagent_tpu.config import Config
     from imagent_tpu.engine import run
 
-    cfg = Config(arch="convnext_tiny", image_size=32, num_classes=8,
+    cfg = Config(backend="cpu", arch="convnext_tiny", image_size=32, num_classes=8,
                  batch_size=8, epochs=1, lr=0.05, dataset="synthetic",
                  synthetic_size=32, workers=0, bf16=False, log_every=0,
                  seed=0, log_dir=str(tmp_path / "tb"),

@@ -58,7 +58,7 @@ def _reset_faults():
 
 
 def _cfg(root, **kw):
-    base = dict(data_root=root, dataset="imagefolder", image_size=16,
+    base = dict(backend="cpu", data_root=root, dataset="imagefolder", image_size=16,
                 num_classes=2, workers=0, seed=0)
     base.update(kw)
     return Config(**base)

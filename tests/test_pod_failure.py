@@ -351,7 +351,7 @@ def test_pod_heartbeat_facade_staleness_gauge(tmp_path):
 
 def _cfg(tmp_path, **kw):
     from imagent_tpu.config import Config
-    base = dict(arch="resnet18", image_size=16, num_classes=4,
+    base = dict(backend="cpu", arch="resnet18", image_size=16, num_classes=4,
                 batch_size=4, epochs=2, lr=0.05, dataset="synthetic",
                 synthetic_size=128, workers=0, bf16=False, log_every=0,
                 seed=0, save_model=True, peer_deadline_secs=1.0,

@@ -92,7 +92,7 @@ def test_engine_pp_sp_smoke(tmp_path):
     from imagent_tpu.config import Config
     from imagent_tpu.engine import run
 
-    cfg = Config(arch="vit_debug", image_size=32, num_classes=4,
+    cfg = Config(backend="cpu", arch="vit_debug", image_size=32, num_classes=4,
                  batch_size=4, epochs=1, lr=0.01, dataset="synthetic",
                  synthetic_size=16, workers=0, bf16=False, log_every=0,
                  seq_parallel="ring", model_parallel=2,

@@ -31,7 +31,7 @@ def texture_root(tmp_path_factory):
 
 
 def _cfg(root, tmp_path, **kw):
-    base = dict(
+    base = dict(backend="cpu",
         arch="resnet18", image_size=32, num_classes=N_CLASSES,
         batch_size=4, epochs=10, lr=0.1, dataset="imagefolder",
         data_root=str(root), augment=True, workers=2, bf16=False,

@@ -22,7 +22,6 @@ from imagent_tpu.train import (
     create_train_state, make_eval_step, make_optimizer, make_train_step,
     place_state, replicate_state, shard_batch, state_partition_specs,
 )
-from imagent_tpu.compat.jaxcompat import shard_map
 
 CLASSES, SIZE, M = 8, 32, 2
 BATCH = 32  # global; dp = 8/(pp=2) = 4 -> per-device 8, micro-batch 4
@@ -104,7 +103,7 @@ def test_pipelined_eval_grads_exact():
         g = jax.tree.map(lambda a: lax.pmean(a, DATA_AXIS), g)
         return normalize_region_grads(g, specs_p, PIPE_AXIS)
 
-    f = jax.jit(shard_map(
+    f = jax.jit(jax.shard_map(
         per_device, mesh=mesh, in_specs=(P(), P(DATA_AXIS), P(DATA_AXIS)),
         out_specs=P(), check_vma=False))
     gi, gl = shard_batch(mesh, images, labels)
@@ -191,7 +190,7 @@ def test_resnet_pp_e2e_from_cli(tmp_path):
     from imagent_tpu.config import Config
     from imagent_tpu.engine import run
 
-    cfg = Config(arch="resnet18", image_size=16, num_classes=4,
+    cfg = Config(backend="cpu", arch="resnet18", image_size=16, num_classes=4,
                  batch_size=4, microbatches=2, pipeline_parallel=2,
                  epochs=2, lr=0.05, dataset="synthetic",
                  synthetic_size=64, workers=0, bf16=False, log_every=0,

@@ -12,7 +12,6 @@ from imagent_tpu.cluster import DATA_AXIS, make_mesh
 from imagent_tpu.ops.attention import dot_product_attention
 from imagent_tpu.parallel.ring_attention import ring_attention
 from imagent_tpu.parallel.ulysses import ulysses_attention
-from imagent_tpu.compat.jaxcompat import shard_map
 
 B, N, H, D = 2, 64, 8, 16  # N_local = 8 on the 8-device mesh
 
@@ -37,7 +36,7 @@ def _sharded(fn, causal):
     def per_device(q, k, v):
         return fn(q, k, v, DATA_AXIS, causal=causal)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         per_device, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False))
 

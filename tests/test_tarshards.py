@@ -95,7 +95,7 @@ def test_tar_matches_imagefolder_batches(tree):
 def test_tar_training_e2e(tree, tmp_path):
     from imagent_tpu.engine import run
 
-    cfg = Config(arch="resnet18", image_size=SIZE, num_classes=2,
+    cfg = Config(backend="cpu", arch="resnet18", image_size=SIZE, num_classes=2,
                  batch_size=1, epochs=1, lr=0.01, dataset="tar",
                  data_root=os.path.join(tree, "tars"), workers=2,
                  bf16=False, log_every=0,

@@ -207,7 +207,7 @@ def test_engine_export_torch(tmp_path):
     from imagent_tpu.engine import run
 
     pt = tmp_path / "exported.pt"
-    cfg = Config(arch="resnet18", image_size=16, num_classes=4,
+    cfg = Config(backend="cpu", arch="resnet18", image_size=16, num_classes=4,
                  batch_size=4, epochs=1, lr=0.01, dataset="synthetic",
                  synthetic_size=32, workers=0, bf16=False, log_every=0,
                  ema_decay=0.5, export_torch=str(pt),
@@ -250,7 +250,7 @@ def test_engine_init_from_torch(tmp_path):
     pt = tmp_path / "imagenet_FR_resnet18.pt"
     torch.save(sd, pt)
 
-    cfg = Config(arch="resnet18", image_size=16, num_classes=4,
+    cfg = Config(backend="cpu", arch="resnet18", image_size=16, num_classes=4,
                  batch_size=4, epochs=1, lr=0.01, dataset="synthetic",
                  synthetic_size=32, workers=0, bf16=False, log_every=0,
                  init_from_torch=str(pt), log_dir=str(tmp_path / "tb"),
@@ -628,7 +628,7 @@ def test_export_torch_prefers_best_checkpoint(tmp_path, capsys):
                   {"epoch": 2, "best_top1": 77.0})
 
     pt = tmp_path / "best.pt"
-    cfg = Config(arch="resnet18", num_classes=4, image_size=16,
+    cfg = Config(backend="cpu", arch="resnet18", num_classes=4, image_size=16,
                  save_model=True, export_torch=str(pt),
                  ckpt_dir=str(tmp_path / "ckpt"))
     _export_torch(cfg, final, is_master=True, prefer_best=True)

@@ -15,7 +15,6 @@ from imagent_tpu.train import (
     create_train_state, make_optimizer, make_train_step, place_state,
     replicate_state, shard_batch,
 )
-from imagent_tpu.compat.jaxcompat import shard_map
 
 SIZE = 16
 BATCH = 16
@@ -64,7 +63,7 @@ def test_zero1_update_bitwise_matches_optax():
     def one_step(p, g, o):
         return zero_lib.sgd_momentum_shard_update(p, g, o, lr, mu, wd)
 
-    stepped = jax.jit(shard_map(
+    stepped = jax.jit(jax.shard_map(
         one_step, mesh=mesh,
         in_specs=(P(), P(), P(DATA_AXIS)), out_specs=(P(), P(DATA_AXIS)),
         check_vma=False))
@@ -146,7 +145,7 @@ def test_zero1_e2e_smoke(tmp_path):
     from imagent_tpu.config import Config
     from imagent_tpu.engine import run
 
-    cfg = Config(arch="resnet18", image_size=16, num_classes=4, batch_size=4,
+    cfg = Config(backend="cpu", arch="resnet18", image_size=16, num_classes=4, batch_size=4,
                  epochs=1, lr=0.05, dataset="synthetic", synthetic_size=64,
                  workers=0, bf16=False, log_every=0, zero1=True,
                  save_model=True, log_dir=str(tmp_path / "tb"),
