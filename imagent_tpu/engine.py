@@ -555,6 +555,11 @@ def train_one_epoch(cfg: Config, mesh, train_step, state: TrainState,
         telem.absorb_input(stats)
         telem.count("quarantined",
                     int(getattr(loader, "quarantined", 0) or 0))
+        # Synthetic batches the generator pool had finished before the
+        # producer asked for them (data/synthetic.py's ring): near the
+        # epoch's batch count, the pool is not what the loop waits on.
+        telem.count("synth_ahead_batches",
+                    int(getattr(loader, "ahead_batches", 0) or 0))
         # Batches the decode-offload service missed (down/unreachable)
         # and local decode carried instead — a dying offload host is a
         # counter + warning, never a silent throughput cliff.

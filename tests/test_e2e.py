@@ -133,3 +133,20 @@ def test_e2e_compile_cache(tmp_path, compile_cache_dir):
     cfg2 = _tiny_cfg(tmp_path, epochs=3, save_model=True, resume=True)
     result = run(cfg2)
     assert result["best_epoch"] >= 0
+
+
+def test_e2e_pooled_synthetic_counts_ring_batches(tmp_path):
+    """With a generator pool, every epoch's telemetry counts the batches
+    the pool had made before the step loop's producer asked for them
+    (``synth_ahead_batches``), at most the epoch's batches; the warmed
+    next epoch and the val loader run through their rings too."""
+    from imagent_tpu.telemetry.events import read_events
+    cfg = _tiny_cfg(tmp_path, workers=2, synthetic_size=192)
+    run(cfg)
+    epochs = [e for e in read_events(str(tmp_path / "tb" / "telemetry.jsonl"))
+              if e["event"] == "epoch"]
+    assert len(epochs) == 2
+    # 6 steps of 32 rows (4 a device, 8 devices): the steps outlast the
+    # pool's 32 tiny samples, so it gets ahead.
+    ahead = [rec["counters"]["synth_ahead_batches"] for rec in epochs]
+    assert all(0 <= a <= 6 for a in ahead) and sum(ahead) > 0, ahead

@@ -221,3 +221,124 @@ def test_trace_rows_records_the_stream(data_root, monkeypatch,
         == [(e, s) for e, s, _ in want]
     for rec, (_, _, rows) in zip(recs, want):
         assert rec["rows"] == [int(x) for x in rows[rows != PAD_ROW]]
+
+
+# ---------------------------------------------------------------------------
+# The synthetic generator pool's shared-memory ring
+# ---------------------------------------------------------------------------
+
+
+def _synth(workers: int, split: str = "train", seed: int = 7):
+    """13 train batches of 4 rows, or 4 val batches (the last one padded),
+    through a ring of 2 slots (prefetch_depth 1)."""
+    from imagent_tpu.data.synthetic import SyntheticLoader
+    cfg = Config(image_size=SIZE, num_classes=3, synthetic_size=52,
+                 workers=workers, prefetch_depth=1, seed=seed)
+    return SyntheticLoader(cfg, 0, 1, 4, train=(split == "train"))
+
+
+def _assert_same(got, want):
+    assert len(got) == len(want)
+    for (ai, al, am), (bi, bl, bm) in zip(got, want):
+        np.testing.assert_array_equal(ai, bi)
+        np.testing.assert_array_equal(al, bl)
+        np.testing.assert_array_equal(am, bm)
+
+
+@pytest.mark.parametrize("split,start_step", [
+    ("train", 0), ("train", 3), ("val", 0), ("val", 1)])
+def test_synthetic_ring_matches_serial(split, start_step):
+    """Pooled batches are the serial ones bit for bit over an epoch
+    longer than the ring, padded val tail and mid-epoch start included.
+    The pooled arrays are kept as yielded (no copy) and compared only
+    after the epoch has ended: a yielded batch is never written again."""
+    serial, pooled = _synth(0, split), _synth(2, split)
+    try:
+        want = _collect(serial, 0, start_step)
+        got = [(b.images, b.labels, b.mask)
+               for b in pooled.epoch(0, start_step=start_step)]
+        assert len(got) > len(pooled._ring)
+        assert not any(np.shares_memory(g[0], pooled._ring) for g in got)
+        _assert_same(got, want)
+    finally:
+        serial.close()
+        pooled.close()
+
+
+def test_synthetic_ring_unwinds_and_is_reused():
+    """An epoch closed after one batch leaves nothing in flight; the
+    next epoch on the same loader yields the serial batches, and
+    ``close()`` leaves no worker process alive."""
+    serial, pooled = _synth(0), _synth(2)
+    try:
+        it = pooled.epoch(0)
+        first = next(it)
+        it.close()
+        assert pooled._owner is None
+        np.testing.assert_array_equal(first.images,
+                                      _collect(serial, 0)[0][0])
+        _assert_same(_collect(pooled, 1), _collect(serial, 1))
+        workers = list(pooled._pool._pool)
+        assert len(workers) == 2
+    finally:
+        serial.close()
+        pooled.close()
+    for w in workers:
+        w.join(timeout=10)
+        assert not w.is_alive()
+
+
+def test_synthetic_ring_interleaved_epochs():
+    """Two epochs of one loader open at once take the ring in turn: each
+    still yields its serial batches."""
+    serial, pooled = _synth(0), _synth(2)
+    try:
+        got0, got1 = [], []
+        for b0, b1 in zip(pooled.epoch(0), pooled.epoch(1, start_step=2)):
+            got0.append((b0.images, b0.labels, b0.mask))
+            got1.append((b1.images, b1.labels, b1.mask))
+        _assert_same(got0, _collect(serial, 0)[:len(got0)])
+        _assert_same(got1, _collect(serial, 1, start_step=2))
+    finally:
+        serial.close()
+        pooled.close()
+
+
+def test_synthetic_ring_counts_batches_made_ahead():
+    """Under a slow consumer the pool finishes batches before they are
+    asked for; the count never exceeds the batches yielded, and the
+    serial path counts none."""
+    import time
+    serial, pooled = _synth(0), _synth(2)
+    try:
+        n = 0
+        for _ in pooled.epoch(0):
+            time.sleep(0.02)
+            n += 1
+        assert 0 < pooled.ahead_batches <= n
+        assert sum(1 for _ in serial.epoch(0)) == n
+        assert serial.ahead_batches == 0
+    finally:
+        serial.close()
+        pooled.close()
+
+
+def test_synthetic_ring_worker_error_reaches_consumer():
+    """A sample a worker cannot make (here a negative noise seed, which
+    the serial path refuses the same way) raises in the consumer, and
+    the loader works again afterwards."""
+    import dataclasses
+    serial, pooled = _synth(0, "val"), _synth(2, "val")
+    try:
+        want = _collect(pooled, 0)
+        for ld in (serial, pooled):
+            good = ld.cfg
+            ld.cfg = dataclasses.replace(good, seed=-10**9)
+            with pytest.raises(ValueError, match="negative"):
+                list(ld.epoch(0))
+            ld.cfg = good
+        assert pooled._owner is None
+        _assert_same(_collect(pooled, 0), want)
+    finally:
+        serial.close()
+        pooled.close()
