@@ -554,7 +554,7 @@ def _cli_warm(engine_argv: list[str]) -> int:
     else:
         accum = cfg.grad_accum
         global_batch = cfg.batch_size * n_data * accum
-    train_step, eval_step, state, _specs = \
+    train_step, eval_step, state, _specs, _init_s = \
         engine_lib._build_model_and_steps(cfg, mesh, n_data, accum,
                                           is_master=True)
     store = ExecutableStore(os.path.join(cache_dir, "aot"))

@@ -1229,10 +1229,12 @@ def _build_model_and_steps(cfg, mesh, n_data: int, accum: int,
     shapes the compiled step and must be in the fingerprint (or in
     the justified ``EXEMPT_FIELDS``).
 
-    Returns ``(train_step, eval_step, state, state_specs)`` with the
-    state already placed on ``mesh``. Pure construction: config
-    validation (including the sp/tp/pp/ep composition rules) happened
-    in ``_run`` before the loaders were built."""
+    Returns ``(train_step, eval_step, state, state_specs, init_s)``
+    with the state already placed on ``mesh``; ``init_s`` is the
+    seconds ``create_train_state`` took until its state was ready.
+    Pure construction: config validation (including the sp/tp/pp/ep
+    composition rules) happened in ``_run`` before the loaders were
+    built."""
     use_sp = cfg.seq_parallel != "none"
     use_tp = cfg.tensor_parallel
     use_pp = cfg.pipeline_parallel > 1
@@ -1257,7 +1259,7 @@ def _build_model_and_steps(cfg, mesh, n_data: int, accum: int,
             cfg.arch, cfg.num_classes, cfg.bf16, gap_readout=True,
             attn_impl=cfg.seq_parallel, seq_axis=cluster.MODEL_AXIS,
             remat=cfg.remat, **pp_kw, **vit_kw)
-        # Same param tree, no mesh-axis ops — usable for host-side init.
+        # Same param tree, no mesh-axis ops — usable for the mesh-free init.
         init_model = create_model(cfg.arch, cfg.num_classes, cfg.bf16,
                                   gap_readout=True, remat=cfg.remat,
                                   **({"stacked": True} if use_pp else {}),
@@ -1272,7 +1274,7 @@ def _build_model_and_steps(cfg, mesh, n_data: int, accum: int,
             cfg.arch, cfg.num_classes, cfg.bf16, attn_impl=cfg.attn,
             expert_axis=cluster.MODEL_AXIS if use_ep else None,
             **moe_kw, **pp_kw, remat=cfg.remat, **vit_kw)
-        # Host-side init twin: same param tree; EP consumes slices of it.
+        # Mesh-free init twin: same param tree; EP consumes slices of it.
         # groups=1 — params don't depend on the capacity grouping, and
         # the init batch (2 images) need not divide the run's groups.
         # Under pp the twin is the layer-stacked pipe-free model.
@@ -1294,7 +1296,7 @@ def _build_model_and_steps(cfg, mesh, n_data: int, accum: int,
             pipe_axis=cluster.PIPE_AXIS, microbatches=cfg.microbatches,
             tp_axis=cluster.MODEL_AXIS if use_tp else None,
             remat=cfg.remat, **vit_kw)
-        # Host-side init uses the layer-stacked pipe-free twin (same
+        # The mesh-free init uses the layer-stacked pipe-free twin (same
         # param tree, parallel/pipeline.py).
         init_model = create_model(cfg.arch, cfg.num_classes, cfg.bf16,
                                   attn_impl=cfg.attn, stacked=True,
@@ -1304,7 +1306,7 @@ def _build_model_and_steps(cfg, mesh, n_data: int, accum: int,
                              attn_impl=cfg.attn,
                              tp_axis=cluster.MODEL_AXIS,
                              remat=cfg.remat, **vit_kw)
-        # Host-side init uses the unsharded twin; TP consumes slices of
+        # The mesh-free init uses the unsharded twin; TP consumes slices of
         # the same param tree (parallel/tensor_parallel.py).
         init_model = create_model(cfg.arch, cfg.num_classes, cfg.bf16,
                                   attn_impl=cfg.attn, remat=cfg.remat,
@@ -1354,9 +1356,13 @@ def _build_model_and_steps(cfg, mesh, n_data: int, accum: int,
     optimizer = make_optimizer(cfg.momentum, cfg.weight_decay,
                                cfg.optimizer)
     # Same seed on every process ⇒ identical init, the DDP broadcast
-    # equivalence (imagenet.py:215,316).
-    state = create_train_state(
-        init_model, jax.random.key(cfg.seed), cfg.image_size, optimizer)
+    # equivalence (imagenet.py:215,316). One jitted program with the key
+    # as its argument; blocked on once, since the first step needs the
+    # state anyway, so `init_s` is the init's whole cost.
+    t_init = time.perf_counter()
+    state = jax.block_until_ready(create_train_state(
+        init_model, jax.random.key(cfg.seed), cfg.image_size, optimizer))
+    init_s = time.perf_counter() - t_init
     if cfg.init_from_torch:
         state = _load_torch_weights(cfg, state)
         if is_master:
@@ -1442,7 +1448,7 @@ def _build_model_and_steps(cfg, mesh, n_data: int, accum: int,
             health_stats=cfg.health_stats)
         eval_step = make_eval_step(model, mesh, state_specs,
                                    mean=cfg.mean, std=cfg.std)
-    return train_step, eval_step, state, state_specs
+    return train_step, eval_step, state, state_specs, init_s
 
 
 def _run(cfg: Config, stop_check, senv, watchdog, pod=None,
@@ -1673,8 +1679,8 @@ def _run(cfg: Config, stop_check, senv, watchdog, pod=None,
         cfg, jax.process_index() // proc_group_size, n_groups,
         global_batch, skip_train=cfg.eval_only)
 
-    train_step, eval_step, state, state_specs = _build_model_and_steps(
-        cfg, mesh, n_data, accum, is_master)
+    train_step, eval_step, state, state_specs, init_s = \
+        _build_model_and_steps(cfg, mesh, n_data, accum, is_master)
 
     # One-compile startup (compilecache.py): lower+compile each step
     # executable ONCE via the AOT path, dispatch through wrappers that
@@ -2116,6 +2122,10 @@ def _run(cfg: Config, stop_check, senv, watchdog, pod=None,
         # own — so the regress gate's startup_compile_s series reads
         # ALL run_start records, not the folded last one.
         "compile_cache": cc_stats,
+        # Seconds the initial TrainState took to build (one jitted
+        # program, served by the compile cache after a checkout's
+        # first run), read by the benchmark's startup.init_s.
+        "init_s": init_s,
     })
     if resized_info is not None:
         # The resize verdict of THIS attempt (restore found a

@@ -23,7 +23,8 @@ TPU-native design, not a port of torch pipeline APIs:
   shards over ``pipe`` (``PartitionSpec("pipe", ...)``): stage *i* holds
   layers ``[i*L/S, (i+1)*L/S)``. With ``pipe_axis=None`` the same module
   (identical param tree) just scans all layers on every device — that
-  twin is used for host-side init and as the numerical reference in tests.
+  twin is used for the mesh-free init and as the numerical reference in
+  tests.
 
 Gradient semantics (see ``train.make_train_step``): the final activation
 is returned via a masked ``psum`` off the last stage, so every pipe shard

@@ -139,12 +139,16 @@ def test_e2e_pooled_synthetic_counts_ring_batches(tmp_path):
     """With a generator pool, every epoch's telemetry counts the batches
     the pool had made before the step loop's producer asked for them
     (``synth_ahead_batches``), at most the epoch's batches; the warmed
-    next epoch and the val loader run through their rings too."""
+    next epoch and the val loader run through their rings too. The run's
+    ``run_start`` carries the seconds the initial state took
+    (``init_s``, the benchmark's ``startup.init_s``)."""
     from imagent_tpu.telemetry.events import read_events
     cfg = _tiny_cfg(tmp_path, workers=2, synthetic_size=192)
     run(cfg)
-    epochs = [e for e in read_events(str(tmp_path / "tb" / "telemetry.jsonl"))
-              if e["event"] == "epoch"]
+    events = read_events(str(tmp_path / "tb" / "telemetry.jsonl"))
+    starts = [e for e in events if e["event"] == "run_start"]
+    assert len(starts) == 1 and starts[0]["init_s"] > 0, starts
+    epochs = [e for e in events if e["event"] == "epoch"]
     assert len(epochs) == 2
     # 6 steps of 32 rows (4 a device, 8 devices): the steps outlast the
     # pool's 32 tiny samples, so it gets ahead.
